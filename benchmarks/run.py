@@ -25,6 +25,11 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    # process set-up, not an engine call -- dragonlint: disable=api-surface
+    from repro.kernels import runtime
+
+    runtime.enable_compile_cache()
+
     from benchmarks import (
         bench_api,
         bench_dse,

@@ -454,7 +454,11 @@ def map_workload_scan(chw: ConcreteHW, g: Graph, cfg: MapperCfg = MapperCfg()) -
         )
         return dict(occupancy=new_occ, bw_ema=new_bw), out
 
-    carry0 = dict(occupancy=jnp.float32(0.0), bw_ema=jnp.float32(0.0))
+    # zeros derived from the hardware point, not literals: when a population
+    # is sharded over a mesh axis the hardware varies along it, and so must
+    # the carry (runtime.spmd_map checks that the two agree)
+    zero = jnp.zeros_like(freq)
+    carry0 = dict(occupancy=zero, bw_ema=zero)
     xs = (g.n_comp, g.n_read, g.n_write, g.n_alloc, g.dims)
     _, outs = jax.lax.scan(vertex_step, carry0, xs)
 

@@ -95,7 +95,11 @@ def _popsim_kernel(graph_ref, chw_ref, out_ref, *, n_vertices: int):
         )
         t_sys = jnp.where(ops_sys_tile > 0, tiles * cyc_sys_tile / freq, 0.0)
         eff = jnp.maximum(rate, 1e-9) * freq[:, None]  # FLOP/s
-        t_other = jnp.max((n_comp[None, :] / eff).at[:, _SYS].set(0.0), axis=-1)
+        t_cls = n_comp[None, :] / eff  # [BP, 4]
+        # the systolic class is priced by the wave model above; an iota mask
+        # (not a scatter, which Mosaic cannot lower) drops its column
+        not_sys = jax.lax.broadcasted_iota(jnp.int32, t_cls.shape, 1) != _SYS
+        t_other = jnp.max(jnp.where(not_sys, t_cls, 0.0), axis=-1)
         t_comp = jnp.maximum(t_other, t_sys)  # [BP]
 
         t_lvl = (n_read + n_write)[None, :] / bw * 1.04  # bank-conflict mean

@@ -1,41 +1,36 @@
-"""Version-adaptive JAX/Pallas runtime layer — the ONE place that touches
-version-fragile JAX API spellings.
-
-JAX has renamed or moved every API the DSim kernels depend on at least once:
-
-  * ``pltpu.TPUCompilerParams`` (<= 0.4.x)  ->  ``pltpu.CompilerParams``
-  * ``jax.experimental.shard_map.shard_map`` ->  ``jax.shard_map``
-  * ``shard_map(..., check_rep=)``           ->  ``shard_map(..., check_vma=)``
+"""The JAX/Pallas runtime seam — the ONE place that spells Pallas compiler
+params, shard-map, executable (de)serialization and the compile cache.
 
 Every kernel and every explicit-SPMD call site routes through this module so
-the rest of the codebase never spells a version-specific name:
+the rest of the codebase never spells these APIs itself:
 
-  * :func:`tpu_compiler_params` — construct TPU compiler params under either
-    class name (returns ``None`` when no TPU Pallas backend is available).
-  * :func:`resolve_shard_map` — return the shard-map entry point under either
-    spelling (``None`` if the installed JAX has neither).
-  * :func:`spmd_map` — the call-site wrapper around :func:`resolve_shard_map`
-    that also adapts the replication-check keyword across versions.
+  * :func:`tpu_compiler_params` — construct ``pltpu.CompilerParams``
+    (``None`` when no TPU Pallas module is available).
+  * :func:`resolve_shard_map` / :func:`spmd_map` — ``jax.shard_map`` with
+    its ``check_vma`` replication check.
   * :func:`dragon_pallas_call` — the single ``pl.pallas_call`` wrapper:
-    backend detection, interpret-mode auto-fallback on non-TPU backends,
-    compiler-params construction, and scratch plumbing.
+    interpret mode on the CPU backend only, Mosaic on TPU, an error
+    anywhere else; compiler-params construction and scratch plumbing.
   * :func:`clamp_block` / :func:`gcd_block` — centralized block-size clamping.
   * :func:`vmem_scratch` — VMEM scratch allocation without importing pltpu.
   * :func:`serialize_compiled` / :func:`deserialize_compiled` /
     :func:`executable_fingerprint` — the executable (de)serialization seam
-    (``jax.experimental.serialize_executable`` on 0.4.x) behind the
-    persistent AOT cache; the fingerprint names the jax/jaxlib/backend an
-    artifact is valid under.
+    (``jax.experimental.serialize_executable``) behind the persistent AOT
+    cache; the fingerprint names the runtime and device an artifact is
+    valid under.
+  * :func:`enable_compile_cache` — JAX's persistent compilation cache at a
+    fixed path (or wherever ``JAX_COMPILATION_CACHE_DIR`` points).
 
 Resolution is performed at call time (never cached) so tests can monkeypatch
-either spelling in and out, and so a process that upgrades its backend
-mid-life (e.g. ``jax.config`` platform switches) stays correct.
+the backend and module attributes.
 """
 from __future__ import annotations
 
 import inspect
 import math
+import os
 import warnings
+from pathlib import Path
 from typing import Any, Callable, Sequence
 
 import jax
@@ -53,12 +48,22 @@ except ImportError:  # pragma: no cover - exercised via monkeypatch in tests
 
 
 def auto_interpret() -> bool:
-    """True when Pallas kernels must run in interpret mode (non-TPU backend).
+    """True when Pallas kernels must run in interpret mode.
 
-    Pallas TPU kernels compile through Mosaic only on a real TPU backend; on
-    CPU/GPU the kernel bodies execute in the Pallas interpreter instead.
+    Kernels compile through Mosaic on the TPU backend and run in the Pallas
+    interpreter on the CPU backend (the test path).  Any other backend is an
+    error: an interpreted kernel there would run, slowly, while the program
+    reported a device run.
     """
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"DSim Pallas kernels compile for 'tpu' and interpret on 'cpu'; "
+        f"backend {backend!r} is neither"
+    )
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -67,29 +72,15 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 # --------------------------------------------------------------------------- #
-# compiler params (TPUCompilerParams <-> CompilerParams)
+# compiler params
 # --------------------------------------------------------------------------- #
 
 
-def _compiler_params_cls():
-    if pltpu is None:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
 def tpu_compiler_params(**kw) -> Any | None:
-    """Build TPU compiler params under whichever class the installed JAX has.
-
-    Returns ``None`` (caller omits the argument) when neither spelling exists,
-    so kernels degrade gracefully on installs without a TPU Pallas backend.
-    Keywords the resolved class does not accept are dropped with the same
-    graceful intent — e.g. ``serial_iteration_hints`` on old versions.
-    """
-    cls = _compiler_params_cls()
+    """Build ``pltpu.CompilerParams``; ``None`` (caller omits the argument)
+    when the install has no TPU Pallas module.  Keywords the class does not
+    accept are dropped."""
+    cls = getattr(pltpu, "CompilerParams", None) if pltpu is not None else None
     if cls is None:
         return None
     try:
@@ -102,61 +93,22 @@ def tpu_compiler_params(**kw) -> Any | None:
 
 
 # --------------------------------------------------------------------------- #
-# shard-map resolution
+# shard-map
 # --------------------------------------------------------------------------- #
 
 
 def resolve_shard_map() -> Callable | None:
-    """Return the shard-map entry point under either spelling.
-
-    Prefers the stable ``jax.shard_map`` (>= 0.5); falls back to
-    ``jax.experimental.shard_map.shard_map`` (0.4.x). ``None`` if neither
-    exists.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    try:
-        from jax.experimental.shard_map import shard_map as legacy_fn
-    except ImportError:
-        return None
-    return legacy_fn
+    """``jax.shard_map``, or ``None`` if the installed JAX lacks it."""
+    return getattr(jax, "shard_map", None)
 
 
 def spmd_map(fn: Callable, *, mesh, in_specs, out_specs, check: bool = True) -> Callable:
-    """Version-adaptive shard-map wrapper — the only sanctioned call site API.
-
-    ``check`` maps onto whichever replication-check keyword the resolved
-    entry point accepts (``check_vma`` on new JAX, ``check_rep`` on 0.4.x).
-    """
+    """Shard-map wrapper — the only sanctioned call site API.  ``check`` is
+    ``jax.shard_map``'s ``check_vma`` (varying-manual-axes type check)."""
     sm = resolve_shard_map()
     if sm is None:
-        raise RuntimeError(
-            "No shard-map implementation found in the installed JAX; "
-            "need jax.shard_map or jax.experimental.shard_map.shard_map."
-        )
-    kw: dict[str, Any] = {}
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # pragma: no cover - builtins etc.
-        params = {}
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            kw[name] = check
-            break
-    else:
-        if not check:
-            # A third keyword rename (or an uninspectable wrapper) must be
-            # visible, not silent: without the kwarg, shard-map runs with its
-            # default check ENABLED at call sites that asked to disable it.
-            warnings.warn(
-                "spmd_map: resolved shard-map accepts neither check_vma nor "
-                "check_rep; check=False could not be forwarded — update "
-                "repro.kernels.runtime for this JAX version.",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        raise RuntimeError("the installed JAX has no jax.shard_map")
+    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
 
 
 # --------------------------------------------------------------------------- #
@@ -199,52 +151,61 @@ def vmem_scratch(shape: Sequence[int], dtype) -> Any:
     return pltpu.VMEM(tuple(shape), dtype)
 
 
+def backend_initialized() -> bool:
+    """Whether this process has initialized a JAX backend — on a TPU host,
+    whether it already holds the chips (a chip serves one process)."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted on the PCI bus without
+    initializing JAX (so without taking them)."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
 def executable_fingerprint() -> str:
     """The runtime identity a serialized executable is only valid under.
 
     Compiled artifacts are specific to the jax/jaxlib pair that lowered
-    them and the backend they were compiled for; the persistent AOT cache
+    them and to the device they were compiled for (one TPU generation's
+    executable does not run on another); the persistent AOT cache
     (:mod:`repro.serving.aotcache`) folds this string into every cache-key
-    digest so an upgraded runtime misses cleanly instead of deserializing
-    a stale executable.
+    digest so a changed runtime or device misses cleanly instead of
+    deserializing a stale executable.
     """
     import jaxlib
 
-    return f"jax={jax.__version__}|jaxlib={jaxlib.__version__}|backend={jax.default_backend()}"
-
-
-def _serialize_executable_module():
-    """The executable (de)serialization seam of the installed JAX, or None.
-
-    jax 0.4.x ships it as ``jax.experimental.serialize_executable``
-    (``serialize`` / ``deserialize_and_load``); post-0.5 exports may move
-    it — adapt here, nowhere else.
-    """
-    try:
-        from jax.experimental import serialize_executable as se
-    except ImportError:  # pragma: no cover - exercised on future jax
-        return None
-    if not (hasattr(se, "serialize") and hasattr(se, "deserialize_and_load")):
-        return None  # pragma: no cover - exercised on future jax
-    return se
+    devices = jax.devices()
+    return (
+        f"jax={jax.__version__}|jaxlib={jaxlib.__version__}"
+        f"|backend={jax.default_backend()}|kind={devices[0].device_kind}"
+        f"|count={len(devices)}"
+    )
 
 
 def serialize_compiled(compiled) -> bytes | None:
     """Serialize a ``jax.stages.Compiled`` into one portable byte string.
 
-    Returns ``None`` when the installed JAX has no serialization seam, when
-    ``compiled`` is not an AOT-compiled stage (plain ``jax.jit`` wrappers
-    cannot be snapshotted), or when the backend refuses — callers treat
-    ``None`` as "this program cannot be persisted", never as an error.
+    Returns ``None`` when ``compiled`` is not an AOT-compiled stage (plain
+    ``jax.jit`` wrappers cannot be snapshotted) or when the backend refuses;
+    callers treat ``None`` as "this program cannot be persisted".  Every
+    ``None`` comes with a ``RuntimeWarning`` that says why, so a cache that
+    persists nothing is visible.
     """
-    se = _serialize_executable_module()
-    if se is None:
-        return None
     import pickle
+
+    from jax.experimental import serialize_executable as se
 
     try:
         payload, in_tree, out_tree = se.serialize(compiled)
-    except Exception:
+    except Exception as e:  # reported, then treated as "cannot persist"
+        warnings.warn(
+            f"serialize_compiled: {type(e).__name__}: {e}", RuntimeWarning, stacklevel=2
+        )
         return None
     return pickle.dumps((payload, in_tree, out_tree), protocol=pickle.HIGHEST_PROTOCOL)
 
@@ -252,16 +213,12 @@ def serialize_compiled(compiled) -> bytes | None:
 def deserialize_compiled(data: bytes):
     """Rehydrate :func:`serialize_compiled` output into a loaded executable.
 
-    Raises on malformed bytes or a missing seam — the cache layer catches,
-    quarantines the source file, and falls back to a fresh compile.
+    Raises on malformed bytes — the cache layer catches, quarantines the
+    source file, and falls back to a fresh compile.
     """
-    se = _serialize_executable_module()
-    if se is None:
-        raise RuntimeError(
-            "installed JAX has no executable-serialization seam "
-            "(jax.experimental.serialize_executable); cannot load AOT cache entries"
-        )
     import pickle
+
+    from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = pickle.loads(data)
     return se.deserialize_and_load(payload, in_tree, out_tree)
@@ -281,11 +238,12 @@ def dragon_pallas_call(
 ) -> Callable:
     """The single ``pl.pallas_call`` wrapper all DSim kernels go through.
 
-    * ``interpret=None`` auto-falls back to interpret mode off-TPU
-      (:func:`auto_interpret`), matching the kernels' CPU test path.
+    * ``interpret=None`` means Mosaic on TPU and the interpreter on the CPU
+      backend (the test path); any other backend raises
+      (:func:`auto_interpret`).
     * ``dimension_semantics`` (plus any extra ``compiler_kw``) is turned into
-      compiler params via :func:`tpu_compiler_params`; when the installed JAX
-      exposes no compiler-params class the argument is omitted entirely.
+      compiler params via :func:`tpu_compiler_params`; when the install has
+      no TPU Pallas module the argument is omitted entirely.
     """
     interpret = resolve_interpret(interpret)
     kwargs: dict[str, Any] = dict(
@@ -304,3 +262,35 @@ def dragon_pallas_call(
         if params is not None:
             kwargs["compiler_params"] = params
     return pl.pallas_call(kernel, **kwargs)
+
+
+# --------------------------------------------------------------------------- #
+# persistent compilation cache
+# --------------------------------------------------------------------------- #
+
+# <checkout>/.jax_cache: fixed, so a later run finds what an earlier one wrote
+# (the path is part of each entry's key); gitignored
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache on a TPU host; returns the
+    directory JAX caches in (``None``: no cache).
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and no
+    directory is set here; otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`.  The minimum compile time is lowered to zero
+    so the ~1 s DSim programs are cached too (JAX's default skips them).
+
+    On a host with no TPU attached this sets nothing: an XLA:CPU executable
+    that JAX's cache hands back cannot be serialized again for the AOT cache
+    (:mod:`repro.serving.aotcache`) — the copy fails when it runs — and CPU
+    compiles are cheap.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+    if local_tpu_chips() == 0:
+        return env_dir
+    if env_dir is None:
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return env_dir or str(COMPILE_CACHE_DIR)

@@ -17,14 +17,16 @@ Validated against the exact per-step recurrence in tests/test_kernels.py.
 This module also hosts :func:`affine_scan` — the first-order affine prefix
 ``s_i = decay * s_{i-1} + b_i`` the DSim mapper's bandwidth-EMA carry
 dispatches through when ``MapperCfg.scan_impl == "pallas"``.  The forward
-runs as a Pallas kernel (state resident in VMEM scratch, sequential grid
-over chunks, through the ``runtime.dragon_pallas_call`` seam); the backward
+runs as a Pallas kernel (each chunk one product with a lower-triangular
+matrix of decay powers, state resident in VMEM scratch across a sequential
+grid over chunks, through the ``runtime.dragon_pallas_call`` seam); the backward
 is the closed-form reversed scan (``custom_vjp``), so the mapper stays
 fully differentiable.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +113,9 @@ def selective_scan_pallas(
 
 
 def _affine_scan_kernel(b_ref, s_ref, state_ref, *, chunk: int, decay: float):
+    """One chunk of the prefix as a matrix product (no per-lane slicing, which
+    Mosaic cannot lower): ``s_i = sum_{j<=i} decay^(i-j) b_j + decay^(i+1) s``
+    where ``s`` is the state carried in from the previous chunk."""
     ci = pl.program_id(0)
 
     @pl.when(ci == 0)
@@ -118,16 +123,16 @@ def _affine_scan_kernel(b_ref, s_ref, state_ref, *, chunk: int, decay: float):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     b = b_ref[...].astype(jnp.float32)  # [1, chunk]
-
-    def step(t, carry):
-        state, out = carry  # [1, 1], [1, chunk]
-        b_t = jax.lax.dynamic_slice(b, (0, t), (1, 1))
-        state = decay * state + b_t
-        out = jax.lax.dynamic_update_slice(out, state, (0, t))
-        return state, out
-
-    state, out = jax.lax.fori_loop(0, chunk, step, (state_ref[...], jnp.zeros_like(b)))
-    state_ref[...] = state
+    src = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)  # j
+    dst = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)  # i
+    lag = (dst - src).astype(jnp.float32)
+    log_decay = math.log(decay)
+    powers = jnp.where(lag >= 0, jnp.exp(jnp.maximum(lag, 0.0) * log_decay), 0.0)
+    within = jnp.dot(b, powers, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)  # [1, chunk]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1).astype(jnp.float32)
+    out = within + state_ref[...] * jnp.exp((pos + 1.0) * log_decay)
+    state_ref[...] = out[:, chunk - 1:]
     s_ref[...] = out.astype(s_ref.dtype)
 
 

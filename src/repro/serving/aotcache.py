@@ -16,9 +16,11 @@ Entries are addressed by :func:`cache_key_digest`: a SHA-256 over
 
   * a cache **schema version** (bump it to invalidate every entry on a
     format change),
-  * the **runtime fingerprint** (jax + jaxlib versions and the backend,
-    from ``repro.kernels.runtime.executable_fingerprint`` — an upgraded
-    runtime misses cleanly instead of deserializing a stale executable),
+  * the **runtime fingerprint** (jax + jaxlib versions, the backend, the
+    device kind and count, from
+    ``repro.kernels.runtime.executable_fingerprint`` — an upgraded runtime
+    or another chip misses cleanly instead of deserializing a stale
+    executable),
   * a **canonical text encoding** of the existing Session program-cache
     key — ``(kind, ArchSpec, MapperCfg, bucket[, objective][, request
     bucket])`` — encoded field-by-field (:func:`canonical_key_text`), never
@@ -169,8 +171,8 @@ class AotCache:
         """Persist one executable; returns True iff a new entry was written.
 
         Skips keys already on disk and programs that cannot be serialized
-        (plain jit wrappers, seam-less jax) — persisting is best-effort,
-        serving never depends on it.
+        (plain jit wrappers; ``serialize_compiled`` warns why) — persisting
+        is best-effort, serving never depends on it.
         """
         path = self._file(key)
         if os.path.exists(path):

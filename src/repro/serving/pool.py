@@ -66,6 +66,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro.kernels import runtime
 from repro.serving import protocol
 from repro.serving.batching import FlushPolicy, IntakeQueue, batch_key, make_chunk_handlers, plan_chunks
 from repro.serving.chaos import ChaosConfig, ChaosInjector
@@ -492,6 +493,7 @@ class MultiProcessDesignService:
             return self
         import repro
 
+        self._check_chips()
         self._dir = tempfile.mkdtemp(prefix="dragon-pool-")
         sock_path = os.path.join(self._dir, "pool.sock")
         self._listener = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
@@ -543,6 +545,27 @@ class MultiProcessDesignService:
         )
         self._loop_thread.start()
         return self
+
+    def _check_chips(self) -> None:
+        """A TPU serves one process at a time: refuse a fleet whose workers
+        could not each hold a chip, instead of letting it hang."""
+        platforms = os.environ.get("JAX_PLATFORMS")
+        if platforms and "tpu" not in platforms.split(","):
+            return  # workers inherit a TPU-free platform list
+        if runtime.backend_initialized():
+            if jax.default_backend() == "tpu":
+                raise RuntimeError(
+                    "MultiProcessDesignService: this process has initialized JAX "
+                    "and holds the TPU, so worker processes cannot reach it; start "
+                    "the service before any JAX work, or serve in-process"
+                )
+            return
+        chips = runtime.local_tpu_chips()
+        if chips and self.workers > chips:
+            raise RuntimeError(
+                f"MultiProcessDesignService: {self.workers} workers but {chips} "
+                "local TPU chip(s); each worker process needs a chip of its own"
+            )
 
     # ------------------------------------------------------------- intake --
     def enqueue(self, q: DesignQuery) -> int:

@@ -129,6 +129,9 @@ def main(argv=None) -> int:
     ap.add_argument("--socket", required=True, help="coordinator's unix socket path")
     ap.add_argument("--id", type=int, required=True, help="worker id assigned by the coordinator")
     args = ap.parse_args(argv)
+    from repro.kernels import runtime
+
+    runtime.enable_compile_cache()
     return serve_forever(args.socket, args.id)
 
 

@@ -30,6 +30,7 @@ import shutil
 import subprocess
 import sys
 
+import jax
 import pytest
 
 from repro.api import Session, Workload
@@ -190,12 +191,18 @@ class TestCacheKeyDigest:
         assert cache_key_digest(_BASE_KEY) != d0
 
     def test_digest_covers_runtime_fingerprint(self, monkeypatch):
+        fp = runtime.executable_fingerprint()
+        devices = jax.devices()
+        kind, count = f"kind={devices[0].device_kind}", f"count={len(devices)}"
+        assert f"|{kind}|" in fp and fp.endswith(f"|{count}")
         d0 = cache_key_digest(_BASE_KEY)
-        monkeypatch.setattr(
-            runtime, "executable_fingerprint",
-            lambda: "jax=9.9.9|jaxlib=9.9.9|backend=tpu",
-        )
-        assert cache_key_digest(_BASE_KEY) != d0
+        for other in (
+            "jax=9.9.9|jaxlib=9.9.9|backend=tpu",
+            fp.replace(kind, "kind=TPU v4"),  # another chip generation
+            fp.replace(count, f"count={len(devices) + 3}"),
+        ):
+            monkeypatch.setattr(runtime, "executable_fingerprint", lambda o=other: o)
+            assert cache_key_digest(_BASE_KEY) != d0, other
 
     def test_unsupported_component_rejected(self):
         with pytest.raises(TypeError, match="unsupported"):

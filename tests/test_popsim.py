@@ -250,7 +250,11 @@ class TestShardedPopulation:
     @pytest.mark.slow
     def test_sharded_matches_single_device_subprocess(self):
         """The same check on a forced 4-device CPU platform, in a subprocess
-        (the in-process platform is pinned to 1 device by conftest)."""
+        (the in-process platform is pinned to 1 device by conftest): the
+        engine-level chunk on an unpadded graph (V < 32, the sequential
+        mapper scan, whose carry must vary over ``pop`` under shard_map's
+        check_vma), and the façade frontier (padded to 32+, the associative
+        scan), each sharded over a 4-device ``pop`` mesh vs one device."""
         script = textwrap.dedent(
             """
             import numpy as np, jax, jax.numpy as jnp
@@ -263,6 +267,7 @@ class TestShardedPopulation:
 
             assert len(jax.devices()) == 4, jax.devices()
             gstack = Graph.stack([get_workload("lstm")])
+            assert gstack.n_comp.shape[1] < 32  # the sequential-scan path
             n_pop, steps = 8, 2
             (tech, arch), spec, _ = seed_population(n_pop, ("base", "edge"), jax.random.PRNGKey(0))
             mixes = (sample_objective_mixes(n_pop), jnp.full((n_pop,), 300.0), jnp.full((n_pop,), jnp.inf))
@@ -275,6 +280,15 @@ class TestShardedPopulation:
             np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), rtol=1e-5, atol=1e-6)
             for l1, l2 in zip(jax.tree.leaves(s1), jax.tree.leaves(s2)):
                 np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-5, atol=1e-6)
+
+            from repro.api import Session
+            sess = Session("base")
+            r4 = sess.frontier("lstm", population=n_pop, steps=steps, mesh=mesh)
+            r1 = sess.frontier("lstm", population=n_pop, steps=steps)
+            np.testing.assert_allclose(r4.raw.history, r1.raw.history, rtol=1e-5, atol=1e-6)
+            assert np.array_equal(r4.raw.front, r1.raw.front)
+            held = {len(x.sharding.device_set) for x in jax.tree.leaves(r4.raw.tech)}
+            assert held == {4}, held
             print("SHARDED_EQUIV_OK")
             """
         )

@@ -1,6 +1,7 @@
-"""Version-adaptive runtime layer: API-spelling resolution under monkeypatch
-(TPUCompilerParams/CompilerParams present or absent, jax.shard_map present or
-absent), interpret-mode auto-fallback, keyword adaptation, block clamping."""
+"""The runtime seam: compiler-params construction under monkeypatch
+(CompilerParams present or absent), jax.shard_map resolution and its
+check_vma keyword, interpret mode on CPU only, block clamping, and the
+persistent compile-cache helper."""
 import functools
 from types import SimpleNamespace
 
@@ -17,24 +18,13 @@ class NewStyleParams:
         self.dimension_semantics = dimension_semantics
 
 
-class OldStyleParams:
-    def __init__(self, dimension_semantics=None):
-        self.dimension_semantics = dimension_semantics
-
-
 class TestCompilerParams:
     def test_prefers_new_spelling(self, monkeypatch):
-        fake = SimpleNamespace(CompilerParams=NewStyleParams, TPUCompilerParams=OldStyleParams)
+        fake = SimpleNamespace(CompilerParams=NewStyleParams)
         monkeypatch.setattr(runtime, "pltpu", fake)
         p = runtime.tpu_compiler_params(dimension_semantics=("parallel",))
         assert isinstance(p, NewStyleParams)
         assert p.dimension_semantics == ("parallel",)
-
-    def test_falls_back_to_old_spelling(self, monkeypatch):
-        fake = SimpleNamespace(TPUCompilerParams=OldStyleParams)
-        monkeypatch.setattr(runtime, "pltpu", fake)
-        p = runtime.tpu_compiler_params(dimension_semantics=("arbitrary",))
-        assert isinstance(p, OldStyleParams)
 
     def test_neither_spelling_returns_none(self, monkeypatch):
         monkeypatch.setattr(runtime, "pltpu", SimpleNamespace())
@@ -53,7 +43,7 @@ class TestCompilerParams:
         assert isinstance(p, NewStyleParams)
 
     def test_real_install_resolves(self):
-        # whatever JAX is installed, one of the two spellings must resolve
+        # the installed JAX ships pltpu.CompilerParams
         p = runtime.tpu_compiler_params(dimension_semantics=("parallel",))
         assert p is not None
 
@@ -63,28 +53,6 @@ class TestShardMapResolution:
         sentinel = lambda *a, **k: "stable"  # noqa: E731
         monkeypatch.setattr(jax, "shard_map", sentinel, raising=False)
         assert runtime.resolve_shard_map() is sentinel
-
-    def test_falls_back_to_experimental(self, monkeypatch):
-        # ensure the stable spelling is truly absent, then expect the
-        # experimental module's entry point
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-        fn = runtime.resolve_shard_map()
-        from jax.experimental.shard_map import shard_map as legacy
-
-        assert fn is legacy
-
-    def test_spmd_map_adapts_check_rep_keyword(self, monkeypatch):
-        seen = {}
-
-        def fake_sm(f, *, mesh, in_specs, out_specs, check_rep=True):
-            seen.update(mesh=mesh, check_rep=check_rep)
-            return f
-
-        monkeypatch.setattr(jax, "shard_map", fake_sm, raising=False)
-        body = lambda x: x  # noqa: E731
-        out = runtime.spmd_map(body, mesh="M", in_specs=(), out_specs=(), check=False)
-        assert out is body
-        assert seen == {"mesh": "M", "check_rep": False}
 
     def test_spmd_map_adapts_check_vma_keyword(self, monkeypatch):
         seen = {}
@@ -97,21 +65,10 @@ class TestShardMapResolution:
         runtime.spmd_map(lambda x: x, mesh="M", in_specs=(), out_specs=(), check=True)
         assert seen == {"check_vma": True}
 
-    def test_spmd_map_warns_when_check_kw_unadaptable(self, monkeypatch):
-        def fake_sm(f, *, mesh, in_specs, out_specs):  # a third rename: no check kw
-            return f
-
-        monkeypatch.setattr(jax, "shard_map", fake_sm, raising=False)
-        with pytest.warns(RuntimeWarning, match="check=False could not be forwarded"):
-            runtime.spmd_map(lambda x: x, mesh="M", in_specs=(), out_specs=(), check=False)
-
-    def test_missing_everywhere_raises(self, monkeypatch):
+    def test_spmd_map_raises_without_shard_map(self, monkeypatch):
         monkeypatch.delattr(jax, "shard_map", raising=False)
-        import jax.experimental.shard_map as sm_mod
-
-        monkeypatch.delattr(sm_mod, "shard_map", raising=False)
         assert runtime.resolve_shard_map() is None
-        with pytest.raises(RuntimeError, match="shard-map"):
+        with pytest.raises(RuntimeError, match="jax.shard_map"):
             runtime.spmd_map(lambda x: x, mesh=None, in_specs=(), out_specs=())
 
 
@@ -121,6 +78,13 @@ class TestDispatch:
         assert runtime.auto_interpret() is True
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert runtime.auto_interpret() is False
+
+    @pytest.mark.parametrize("backend", ["gpu", "METAL"])
+    def test_other_backends_refuse_to_interpret(self, monkeypatch, backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        with pytest.raises(RuntimeError, match=repr(backend)):
+            runtime.resolve_interpret(None)
+        assert runtime.resolve_interpret(True) is True  # explicit stays explicit
 
     @pytest.mark.parametrize("backend,expect_interpret", [("cpu", True), ("tpu", False)])
     def test_dragon_pallas_call_mode_selection(self, monkeypatch, backend, expect_interpret):
@@ -217,3 +181,39 @@ class TestBlockClamping:
         for block, size in [(128, 300), (128, 128), (7, 13), (1000, 4)]:
             b = runtime.gcd_block(block, size)
             assert b >= 1 and size % b == 0
+
+
+class TestCompileCache:
+    def _updates(self, monkeypatch, chips=1):
+        seen = {}
+        monkeypatch.setattr(runtime, "local_tpu_chips", lambda: chips)
+        monkeypatch.setattr(jax.config, "update", lambda k, v: seen.__setitem__(k, v))
+        return seen
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        seen = self._updates(monkeypatch)
+        assert runtime.enable_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in seen
+        assert seen["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+    def test_default_is_fixed_path_in_checkout(self, monkeypatch):
+        import tempfile
+        from pathlib import Path
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        seen = self._updates(monkeypatch)
+        got = runtime.enable_compile_cache()
+        assert got == seen["jax_compilation_cache_dir"] == runtime.enable_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert Path(got) == repo / ".jax_cache"
+        assert not Path(got).is_relative_to(tempfile.gettempdir())
+        assert seen["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+    def test_no_tpu_attached_sets_nothing(self, monkeypatch, tmp_path):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        seen = self._updates(monkeypatch, chips=0)
+        assert runtime.enable_compile_cache() is None
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert runtime.enable_compile_cache() == str(tmp_path)  # JAX's own reading
+        assert seen == {}

@@ -11,6 +11,7 @@ import sys
 import tempfile
 import textwrap
 
+import jax
 import pytest
 
 from repro.serving import (
@@ -415,6 +416,31 @@ class TestMultiProcess:
 
         with pytest.raises(TypeError, match="process boundary"):
             MultiProcessDesignService(Architecture("edge"), cache_dir=cache_dir)
+
+    def test_refuses_when_this_process_holds_the_tpu(self, monkeypatch, tmp_path):
+        from repro.kernels import runtime
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(runtime, "backend_initialized", lambda: True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mp = MultiProcessDesignService("base", workers=1, cache_dir=tmp_path)
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            mp.start()
+        assert not mp._workers  # refused before spawning anything
+
+    def test_refuses_more_workers_than_chips(self, monkeypatch, tmp_path):
+        from repro.kernels import runtime
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(runtime, "backend_initialized", lambda: False)
+        monkeypatch.setattr(runtime, "local_tpu_chips", lambda: 1)
+        mp = MultiProcessDesignService("base", workers=2, cache_dir=tmp_path)
+        with pytest.raises(RuntimeError, match="2 workers but 1 local TPU chip"):
+            mp.start()
+        assert not mp._workers
+        # a CPU-only platform list is not bound by the chip count
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        mp._check_chips()
 
 
 # --------------------------------------------------------------------------- #

@@ -36,8 +36,11 @@ from tools.dragonlint.engine import REPO_ROOT, Finding
 KINDS = ("simulate", "explain", "optimize", "frontier")
 DEFAULT_WORKLOAD = "bert_base"
 
-# host-callback primitive names (jax 0.4.x spellings)
-CALLBACK_PRIMS = {"debug_callback", "pure_callback", "io_callback", "callback", "outside_call"}
+# host-callback primitive names; jax.debug.print lowers to debug_print
+CALLBACK_PRIMS = {
+    "debug_callback", "debug_print", "pure_callback", "io_callback", "callback",
+    "outside_call",
+}
 # mid-program host<->device / placement transfers.  jnp.asarray over tiny
 # static config (spec masks) lowers to an ALIAS-semantics device_put of a
 # constant — free at dispatch, constant-folded by XLA — so the rule only
