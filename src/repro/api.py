@@ -394,6 +394,44 @@ def _flatten(tree) -> np.ndarray:
     return np.concatenate([np.atleast_1d(np.asarray(x)) for x in jax.tree.leaves(tree)])
 
 
+_ELASTICITY_NAMES: tuple | None = None
+
+
+def _attributed(rep: SimReport, objective: str, elast: np.ndarray) -> SimReport:
+    """``rep`` with every technology and architecture parameter ranked by
+    |elasticity| (``elast`` in tech-then-arch order)."""
+    global _ELASTICITY_NAMES
+    if _ELASTICITY_NAMES is None:
+        _ELASTICITY_NAMES = tuple(f"tech.{n}" for n in tech_param_names()) + tuple(
+            f"arch.{n}" for n in _arch_param_names()
+        )
+    ranked = sorted(zip(_ELASTICITY_NAMES, elast.tolist()), key=lambda kv: -abs(kv[1]))
+    attribution = tuple(Attribution(parameter=n, elasticity=float(v)) for n, v in ranked)
+    return dataclasses.replace(rep, objective=objective, attribution=attribution)
+
+
+def _report_arrays(perfs: PerfEstimate, extras: dict) -> dict:
+    """The fields a :class:`SimReport` reads, as host arrays: one
+    device-to-host transfer per field (a no-op on arrays already fetched)."""
+    state = perfs.state
+    return dict(
+        reads=np.asarray(state.reads),
+        writes=np.asarray(state.writes),
+        comp_ops=np.asarray(state.comp_ops),
+        bw_util=np.asarray(state.bw_util),
+        extras={k: np.asarray(v) for k, v in extras.items()},
+        runtime=np.asarray(perfs.runtime),
+        energy=np.asarray(perfs.energy),
+        power=np.asarray(perfs.power),
+        edp=np.asarray(perfs.edp),
+        cycles=np.asarray(perfs.cycles),
+        energy_mem=np.asarray(perfs.energy_mem),
+        energy_comp=np.asarray(perfs.energy_comp),
+        energy_leak=np.asarray(perfs.energy_leak),
+        area=np.asarray(perfs.area),
+    )
+
+
 class Session:
     """The suite front door: simulate / optimize / frontier / explain
     against one architecture, with compiled programs cached across calls.
@@ -438,6 +476,7 @@ class Session:
         self._plock = threading.RLock()
         self._aot = None
         self.disk_loaded = 0  # programs rehydrated from cache_dir at construction
+        instrument.install_gc_spans()
         if cache_dir is not None:
             # deferred: the serving package (and its fault taxonomy) only
             # loads for sessions that opt into persistence
@@ -786,6 +825,12 @@ class Session:
             seconds=round(time.perf_counter() - t0, 3),
         )
 
+    def _batch_span_args(self, workloads) -> dict:
+        """``n`` and ``bucket`` of a batched call, for its span."""
+        if not workloads:
+            return dict(n=0)
+        return dict(n=len(workloads), bucket=self._workload(workloads[0]).bucket)
+
     def _assemble_batch(self, workloads, architectures, request_bucket=None):
         """Validate + stack a request batch: every item must share the
         session's spec and one shape bucket (that is what makes the stacks
@@ -852,15 +897,16 @@ class Session:
         across batch compositions at one ``request_bucket`` — pinned by
         test — the batch only amortizes dispatch overhead across requests.
         """
-        ws, archs, nb, stacked = self._assemble_batch(
-            workloads, architectures, request_bucket
-        )
-        return self._simulate_batch_assembled(ws, archs, nb, stacked)
+        with instrument.span("dragon.session.simulate_batch", lambda: self._batch_span_args(workloads)):
+            ws, archs, nb, stacked = self._assemble_batch(
+                workloads, architectures, request_bucket
+            )
+            return self._simulate_batch_assembled(ws, archs, nb, stacked)
 
     def _simulate_batch_assembled(self, ws, archs, nb, stacked) -> list[SimReport]:
-        techs, arch_ps, gstacks = stacked
         prog = self._batched_report_program(nb, ws[0].bucket, archs[0].spec, self.mcfg)
-        perfs, extras = prog(techs, arch_ps, gstacks)
+        with instrument.span("dragon.session.launch", program="report_batched"):
+            perfs, extras = prog(*stacked)
         return self._reports_from_batch(ws, archs, perfs, extras)
 
     def _reports_from_batch(self, ws, archs, perfs, extras) -> list[SimReport]:
@@ -869,17 +915,21 @@ class Session:
         :meth:`simulate_batch` and the serving pool's staging-buffer
         dispatcher, so both paths build reports from identical bits."""
         # one device->host sync for the whole batch, then numpy views per lane
-        perfs = jax.tree.map(np.asarray, perfs)
-        extras = {k: np.asarray(v) for k, v in extras.items()}
-        return [
-            self._build_report(
-                archs[i],
-                ws[i],
-                jax.tree.map(lambda x: x[i], perfs),
-                {k: v[i] for k, v in extras.items()},
-            )
-            for i in range(len(ws))
-        ]
+        with instrument.span("dragon.session.fetch"):
+            perfs = jax.tree.map(np.asarray, perfs)
+            extras = {k: np.asarray(v) for k, v in extras.items()}
+        with instrument.span("dragon.session.report", lanes=len(ws)):
+            return [
+                self._build_report(
+                    archs[i],
+                    ws[i],
+                    _report_arrays(
+                        jax.tree.map(lambda x: x[i], perfs),
+                        {k: v[i] for k, v in extras.items()},
+                    ),
+                )
+                for i in range(len(ws))
+            ]
 
     def explain_batch(
         self, workloads, *, objective: str = "edp", architectures=None,
@@ -889,41 +939,35 @@ class Session:
         vmapped gradient dispatch answer N same-bucket explain queries.
         Reports (attribution included) are bit-identical across batch
         compositions at one ``request_bucket``."""
-        ws, archs, nb, stacked = self._assemble_batch(
-            workloads, architectures, request_bucket
-        )
-        techs, arch_ps, gstacks = stacked
-        reports = self._simulate_batch_assembled(ws, archs, nb, stacked)
-        prog = self._batched_explain_program(
-            nb, ws[0].bucket, archs[0].spec, self.mcfg, objective
-        )
-        g_techs, g_archs = prog(techs, arch_ps, gstacks)
-        return self._attribute_batch(reports, g_techs, g_archs, objective)
+        with instrument.span("dragon.session.explain_batch", lambda: self._batch_span_args(workloads)):
+            ws, archs, nb, stacked = self._assemble_batch(
+                workloads, architectures, request_bucket
+            )
+            reports = self._simulate_batch_assembled(ws, archs, nb, stacked)
+            prog = self._batched_explain_program(
+                nb, ws[0].bucket, archs[0].spec, self.mcfg, objective
+            )
+            with instrument.span("dragon.session.launch", program="explain_batched"):
+                g_techs, g_archs = prog(*stacked)
+            return self._attribute_batch(reports, g_techs, g_archs, objective)
 
     def _attribute_batch(self, reports, g_techs, g_archs, objective) -> list[SimReport]:
         """Finish a batched explain dispatch: rank the ``[nb]``-leading
         gradient outputs into per-lane attributions.  Shared by
         :meth:`explain_batch` and the serving pool's staging-buffer
         dispatcher."""
-        g_techs = jax.tree.map(np.asarray, g_techs)
-        g_archs = jax.tree.map(np.asarray, g_archs)
-        names = [f"tech.{n}" for n in tech_param_names()] + [
-            f"arch.{n}" for n in _arch_param_names()
-        ]
-        out = []
-        for i, rep in enumerate(reports):
-            elast = np.concatenate([
-                _flatten(jax.tree.map(lambda x: x[i], g_techs)),
-                _flatten(jax.tree.map(lambda x: x[i], g_archs)),
-            ])
-            ranked = sorted(zip(names, elast.tolist()), key=lambda kv: -abs(kv[1]))
-            attribution = tuple(
-                Attribution(parameter=n, elasticity=float(v)) for n, v in ranked
-            )
-            out.append(
-                dataclasses.replace(rep, objective=objective, attribution=attribution)
-            )
-        return out
+        with instrument.span("dragon.session.fetch"):
+            g_techs = jax.tree.map(np.asarray, g_techs)
+            g_archs = jax.tree.map(np.asarray, g_archs)
+        with instrument.span("dragon.session.attribute", lanes=len(reports)):
+            out = []
+            for i, rep in enumerate(reports):
+                elast = np.concatenate([
+                    _flatten(jax.tree.map(lambda x: x[i], g_techs)),
+                    _flatten(jax.tree.map(lambda x: x[i], g_archs)),
+                ])
+                out.append(_attributed(rep, objective, elast))
+            return out
 
     # ------------------------------------------------------------ simulate --
     def perf(self, workload, *, architecture=None) -> PerfEstimate:
@@ -938,10 +982,14 @@ class Session:
         """Simulate the workload set; returns a :class:`SimReport` with
         per-workload totals and per-memory-level / per-vertex breakdowns."""
         w, a = self._workload(workload), self._arch(architecture)
-        perfs, extras = self._report_program(w.bucket, a.spec, self.mcfg)(
-            a.tech, a.arch, w.stacked
-        )
-        return self._build_report(a, w, perfs, extras)
+        with instrument.span("dragon.session.simulate", bucket=w.bucket, n=1):
+            prog = self._report_program(w.bucket, a.spec, self.mcfg)
+            with instrument.span("dragon.session.launch", program="report"):
+                perfs, extras = prog(a.tech, a.arch, w.stacked)
+            with instrument.span("dragon.session.fetch"):
+                arrays = _report_arrays(perfs, extras)
+            with instrument.span("dragon.session.report", lanes=1):
+                return self._build_report(a, w, arrays)
 
     def explain(self, workload, *, objective: str = "edp", architecture=None) -> SimReport:
         """:meth:`simulate` + gradient-based bottleneck attribution: every
@@ -949,17 +997,15 @@ class Session:
         d log(objective) / d log(parameter) — DOpt's Table-3 signal, served
         as an explanation instead of a descent direction."""
         w, a = self._workload(workload), self._arch(architecture)
-        rep = self.simulate(w, architecture=a)
-        g_tech, g_arch = self._explain_program(w.bucket, a.spec, self.mcfg, objective)(
-            a.tech, a.arch, w.stacked
-        )
-        names = [f"tech.{n}" for n in tech_param_names()] + [
-            f"arch.{n}" for n in _arch_param_names()
-        ]
-        elast = np.concatenate([_flatten(g_tech), _flatten(g_arch)])
-        ranked = sorted(zip(names, elast.tolist()), key=lambda kv: -abs(kv[1]))
-        attribution = tuple(Attribution(parameter=n, elasticity=float(v)) for n, v in ranked)
-        return dataclasses.replace(rep, objective=objective, attribution=attribution)
+        with instrument.span("dragon.session.explain", bucket=w.bucket, n=1):
+            rep = self.simulate(w, architecture=a)
+            prog = self._explain_program(w.bucket, a.spec, self.mcfg, objective)
+            with instrument.span("dragon.session.launch", program="explain"):
+                g_tech, g_arch = prog(a.tech, a.arch, w.stacked)
+            with instrument.span("dragon.session.fetch"):
+                elast = np.concatenate([_flatten(g_tech), _flatten(g_arch)])
+            with instrument.span("dragon.session.attribute", lanes=1):
+                return _attributed(rep, objective, elast)
 
     # ------------------------------------------------------------ optimize --
     def optimize(
@@ -988,45 +1034,46 @@ class Session:
         mode where only the descent itself should be on the clock.
         """
         w, a = self._workload(workload), self._arch(architecture)
-        mcfg = engine_kw.pop("mcfg", self.mcfg)
-        # everything static to the engine's fused-chunk program belongs in
-        # the key: steps/target_factor/chunk set the scan length, and
-        # fused/area_constraint are static argnames of _fused_chunk
-        self._engine_call(
-            ("optimize", a.spec, mcfg, w.bucket, objective, opt_over, steps,
-             engine_kw.get("fused", True), engine_kw.get("chunk"),
-             engine_kw.get("target_factor"), engine_kw.get("area_constraint"))
-        )
-        res = _dopt.optimize(
-            w.stacked,
-            tech=a.tech,
-            arch=a.arch,
-            spec=a.spec,
-            objective=objective,
-            opt_over=opt_over,
-            steps=steps,
-            lr=lr,
-            mcfg=mcfg,
-            **engine_kw,
-        )
-        opt_arch = Architecture(
-            None, name=f"{a.name}_opt", tech=res.tech, arch=res.arch, spec=a.spec
-        )
-        hist = tuple(float(math.exp(v)) for v in res.history["objective"])
-        improvement = hist[0] / max(hist[-1], 1e-300) if hist else 1.0
-        return OptResult(
-            objective=objective,
-            opt_over=opt_over,
-            epochs=len(hist),
-            improvement=improvement,
-            objective_history=hist,
-            importance=tuple(
-                Attribution(parameter=f"tech.{n}", elasticity=v) for n, v in res.importance
-            ),
-            baseline=self.simulate(w, architecture=a) if report else None,
-            optimized=self.simulate(w, architecture=opt_arch) if report else None,
-            dhd=opt_arch.to_dhd(),
-        )
+        with instrument.span("dragon.session.optimize", bucket=w.bucket, n=1):
+            mcfg = engine_kw.pop("mcfg", self.mcfg)
+            # everything static to the engine's fused-chunk program belongs in
+            # the key: steps/target_factor/chunk set the scan length, and
+            # fused/area_constraint are static argnames of _fused_chunk
+            self._engine_call(
+                ("optimize", a.spec, mcfg, w.bucket, objective, opt_over, steps,
+                 engine_kw.get("fused", True), engine_kw.get("chunk"),
+                 engine_kw.get("target_factor"), engine_kw.get("area_constraint"))
+            )
+            res = _dopt.optimize(
+                w.stacked,
+                tech=a.tech,
+                arch=a.arch,
+                spec=a.spec,
+                objective=objective,
+                opt_over=opt_over,
+                steps=steps,
+                lr=lr,
+                mcfg=mcfg,
+                **engine_kw,
+            )
+            opt_arch = Architecture(
+                None, name=f"{a.name}_opt", tech=res.tech, arch=res.arch, spec=a.spec
+            )
+            hist = tuple(float(math.exp(v)) for v in res.history["objective"])
+            improvement = hist[0] / max(hist[-1], 1e-300) if hist else 1.0
+            return OptResult(
+                objective=objective,
+                opt_over=opt_over,
+                epochs=len(hist),
+                improvement=improvement,
+                objective_history=hist,
+                importance=tuple(
+                    Attribution(parameter=f"tech.{n}", elasticity=v) for n, v in res.importance
+                ),
+                baseline=self.simulate(w, architecture=a) if report else None,
+                optimized=self.simulate(w, architecture=opt_arch) if report else None,
+                dhd=opt_arch.to_dhd(),
+            )
 
     def tech_targets(self, workload, *, goal_factor: float = 100.0, **engine_kw) -> dict:
         """Technology targets for a ``goal_factor``x objective improvement
@@ -1058,48 +1105,49 @@ class Session:
         ``sigma``, ``mesh``, ``key``, ``hv_box``, ...).
         """
         w = self._workload(workload)
-        mcfg = engine_kw.pop("mcfg", self.mcfg)
-        self._engine_call(
-            ("frontier", mcfg, w.bucket, tuple(metrics), tuple(seeds),
-             population, steps, engine_kw.get("chunk"), engine_kw.get("opt_over", "both"))
-        )
-        res = _popsim.pareto_dse(
-            w.stacked,
-            seeds=seeds,
-            population=population,
-            steps=steps,
-            lr=lr,
-            metrics=metrics,
-            area_budget=area_budget,
-            power_budget=power_budget,
-            mcfg=mcfg,
-            **engine_kw,
-        )
-        front = tuple(
-            FrontierPoint(
-                index=int(win["index"]),
-                seed=win["seed"],
-                weights=tuple(win["weights"][m] for m in PARETO_METRICS),
-                time_s=win["time_s"],
-                energy_j=win["energy_j"],
-                area_mm2=win["area_mm2"],
-                power_w=win["power_w"],
-                edp=win["edp"],
-                dhd=win["dhd"],
+        with instrument.span("dragon.session.frontier", bucket=w.bucket, n=1):
+            mcfg = engine_kw.pop("mcfg", self.mcfg)
+            self._engine_call(
+                ("frontier", mcfg, w.bucket, tuple(metrics), tuple(seeds),
+                 population, steps, engine_kw.get("chunk"), engine_kw.get("opt_over", "both"))
             )
-            for win in res.winners
-        )
-        return FrontierResult(
-            metrics=tuple(metrics),
-            population=population,
-            epochs=steps,
-            feasible=int(res.feasible.sum()),
-            hypervolume=float(res.hypervolume),
-            area_budget=float("inf") if area_budget is None else float(area_budget),
-            power_budget=float("inf") if power_budget is None else float(power_budget),
-            front=front,
-            raw=res,
-        )
+            res = _popsim.pareto_dse(
+                w.stacked,
+                seeds=seeds,
+                population=population,
+                steps=steps,
+                lr=lr,
+                metrics=metrics,
+                area_budget=area_budget,
+                power_budget=power_budget,
+                mcfg=mcfg,
+                **engine_kw,
+            )
+            front = tuple(
+                FrontierPoint(
+                    index=int(win["index"]),
+                    seed=win["seed"],
+                    weights=tuple(win["weights"][m] for m in PARETO_METRICS),
+                    time_s=win["time_s"],
+                    energy_j=win["energy_j"],
+                    area_mm2=win["area_mm2"],
+                    power_w=win["power_w"],
+                    edp=win["edp"],
+                    dhd=win["dhd"],
+                )
+                for win in res.winners
+            )
+            return FrontierResult(
+                metrics=tuple(metrics),
+                population=population,
+                epochs=steps,
+                feasible=int(res.feasible.sum()),
+                hypervolume=float(res.hypervolume),
+                area_budget=float("inf") if area_budget is None else float(area_budget),
+                power_budget=float("inf") if power_budget is None else float(power_budget),
+                front=front,
+                raw=res,
+            )
 
     # --------------------------------------------------------- introspection --
     def trace_programs(self, workload, *, objective: str = "edp", architecture=None) -> dict:
@@ -1184,23 +1232,15 @@ class Session:
         return out
 
     # -------------------------------------------------------------- report --
-    def _build_report(self, a: Architecture, w: Workload, perfs, extras) -> SimReport:
-        state = perfs.state
-        reads = np.asarray(state.reads)
-        writes = np.asarray(state.writes)
-        comp_ops = np.asarray(state.comp_ops)
-        bw_util = np.asarray(state.bw_util)
-        ex = {k: np.asarray(v) for k, v in extras.items()}
-        runtime = np.asarray(perfs.runtime)
-        # one host sync per field, outside the per-workload loop
-        energy = np.asarray(perfs.energy)
-        power = np.asarray(perfs.power)
-        edp = np.asarray(perfs.edp)
-        cycles = np.asarray(perfs.cycles)
-        energy_mem = np.asarray(perfs.energy_mem)
-        energy_comp = np.asarray(perfs.energy_comp)
-        energy_leak = np.asarray(perfs.energy_leak)
-        area = np.asarray(perfs.area)
+    def _build_report(self, a: Architecture, w: Workload, arrays: dict) -> SimReport:
+        """A :class:`SimReport` from the host arrays :func:`_report_arrays`
+        fetched."""
+        reads, writes = arrays["reads"], arrays["writes"]
+        comp_ops, bw_util = arrays["comp_ops"], arrays["bw_util"]
+        runtime, energy, power = arrays["runtime"], arrays["energy"], arrays["power"]
+        edp, cycles, area = arrays["edp"], arrays["cycles"], arrays["area"]
+        energy_mem, energy_comp = arrays["energy_mem"], arrays["energy_comp"]
+        energy_leak, ex = arrays["energy_leak"], arrays["extras"]
         workloads = []
         for i, (lbl, g) in enumerate(zip(w.labels, w.graphs)):
             v = g.n_vertices

@@ -270,7 +270,6 @@ def optimize(
     lr: float = 0.05,
     mcfg: MapperCfg = MapperCfg(),
     target_factor: float | None = None,  # stop when obj improves by this factor
-    log_every: int = 0,
     fused: bool = True,  # device-resident chunked-scan epochs (False: per-step loop)
     chunk: int | None = None,  # epochs per device dispatch when fused
     objective_weights=None,  # [4] PARETO_METRICS mix, for objective="mixed"
@@ -383,31 +382,24 @@ def optimize(
     executed = 0
     if fused:
         chunk = _default_chunk(steps, target_factor) if chunk is None else max(1, chunk)
+    else:
+        chunk = 1  # one jitted dispatch and one host sync per epoch
+    with instrument.span("dragon.dopt.descent", steps=steps, chunk_epochs=chunk):
         while executed < steps:
             n = min(chunk, steps - executed)
-            faults = jnp.asarray(fault_np[executed:executed + n])
-            (state, elast_acc), metrics = _fused_chunk(state, elast_acc, gstack, lr_arr, mix, faults, n=n, **static)
-            executed += n
-            _append(np.asarray(metrics))  # the one host sync per chunk
-            if log_every:
-                for i in range(executed - n, executed, log_every):
-                    print(
-                        f"  dopt step {i:4d}  obj={hist['objective'][i]:.4f} "
-                        f"runtime={hist['runtime'][i]:.3e}s energy={hist['energy'][i]:.3e}J"
+            with instrument.span("dragon.dopt.chunk", epochs=n):
+                if fused:
+                    faults = jnp.asarray(fault_np[executed:executed + n])
+                    (state, elast_acc), metrics = _fused_chunk(
+                        state, elast_acc, gstack, lr_arr, mix, faults, n=n, **static
                     )
-            if _target_met():
-                break
-    else:
-        for i in range(steps):
-            state, elast, metrics = step_jit(state, jnp.float32(fault_np[i]))
-            elast_acc = elast_acc + jnp.abs(elast)
-            executed += 1
-            _append(np.asarray(metrics)[None])
-            if log_every and i % log_every == 0:
-                print(
-                    f"  dopt step {i:4d}  obj={hist['objective'][i]:.4f} "
-                    f"runtime={hist['runtime'][i]:.3e}s energy={hist['energy'][i]:.3e}J"
-                )
+                else:
+                    state, elast, metrics = step_jit(state, jnp.float32(fault_np[executed]))
+                    elast_acc = elast_acc + jnp.abs(elast)
+                with instrument.span("dragon.dopt.sync"):
+                    m = np.asarray(metrics)  # the one host sync per chunk
+            executed += n
+            _append(m if fused else m[None])
             if _target_met():
                 break
 

@@ -66,6 +66,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro.core import instrument
 from repro.kernels import runtime
 from repro.serving import protocol
 from repro.serving.batching import FlushPolicy, IntakeQueue, batch_key, make_chunk_handlers, plan_chunks
@@ -176,9 +177,11 @@ class StagedBatchingService(BatchingDesignService):
         ws = [a.w for a in adms]
         archs = [a.arch for a in adms]
         bucket, spec = ws[0].bucket, archs[0].spec
-        staged = self._assembler.stage(ws, archs)
+        with instrument.span("dragon.service.stage"):
+            staged = self._assembler.stage(ws, archs)
         prog = sess._batched_report_program(self.request_bucket, bucket, spec, sess.mcfg)
-        perfs, extras = prog(*staged)
+        with instrument.span("dragon.session.launch", program="report_batched"):
+            perfs, extras = prog(*staged)
         reports = sess._reports_from_batch(ws, archs, perfs, extras)
         if kind == "simulate":
             return reports
@@ -186,7 +189,8 @@ class StagedBatchingService(BatchingDesignService):
         eprog = sess._batched_explain_program(
             self.request_bucket, bucket, spec, sess.mcfg, objective
         )
-        g_techs, g_archs = eprog(*staged)
+        with instrument.span("dragon.session.launch", program="explain_batched"):
+            g_techs, g_archs = eprog(*staged)
         return sess._attribute_batch(reports, g_techs, g_archs, objective)
 
 
@@ -226,6 +230,7 @@ class PooledDesignService(StagedBatchingService):
         super().__init__(architecture, policy=policy, **kw)
         self.workers = max(1, int(workers))
         self._ticket = itertools.count()
+        self._chunk_ids = itertools.count()  # the `chunk` arg of its spans
         self._cond = threading.Condition()
         self._pending = 0
         self._results: dict[int, DesignReply] = {}
@@ -320,44 +325,61 @@ class PooledDesignService(StagedBatchingService):
     def _process(self, items: list) -> None:
         """Intake + plan one drained batch, then fan chunks out to the
         pool.  Mirrors the synchronous ``flush`` accounting exactly."""
-        admitted: list = []
-        ticket_of: dict[int, int] = {}
-        for i, (t_enq, (ticket, q)) in enumerate(items):
-            ticket_of[i] = ticket
-            try:
-                prep = self._prepare(q)
-            except Exception as e:
-                prep = self._last_ditch(q, e)
-            if isinstance(prep, DesignReply):
-                self._finish(ticket, prep)
-            else:
-                prep.t0 = t_enq  # wall time includes the queue wait
-                admitted.append((i, prep))
-        for chunk in plan_chunks(admitted, self.policy.max_batch):
-            handler_of: dict = {}
-            if len(chunk) >= self._coalesce_min and batch_key(chunk[0][1]) is not None:
-                handler_of = make_chunk_handlers(chunk, self._dispatch_chunk)
-                if len(chunk) > 1:
-                    with self._mutex:
-                        self._batches += 1
-                        self._batched_queries += len(chunk)
-            try:
-                self._exec.submit(self._run_chunk, chunk, handler_of, ticket_of)
-            except RuntimeError:  # pool shut down mid-close: finish inline
-                self._run_chunk(chunk, handler_of, ticket_of)
+        t_drain = self._clock()
+        with instrument.span("dragon.service.intake", n=len(items)):
+            admitted: list = []
+            ticket_of: dict[int, int] = {}
+            for i, (t_enq, (ticket, q)) in enumerate(items):
+                ticket_of[i] = ticket
+                try:
+                    prep = self._prepare(q)
+                except Exception as e:
+                    prep = self._last_ditch(q, e)
+                if isinstance(prep, DesignReply):
+                    self._finish(ticket, prep)
+                else:
+                    prep.t0 = t_enq  # wall time includes the queue wait
+                    admitted.append((i, prep))
+            for chunk in plan_chunks(admitted, self.policy.max_batch):
+                handler_of: dict = {}
+                if len(chunk) >= self._coalesce_min and batch_key(chunk[0][1]) is not None:
+                    handler_of = make_chunk_handlers(chunk, self._dispatch_chunk)
+                    if len(chunk) > 1:
+                        with self._mutex:
+                            self._batches += 1
+                            self._batched_queries += len(chunk)
+                args = (chunk, handler_of, ticket_of, next(self._chunk_ids), t_drain)
+                try:
+                    self._exec.submit(self._run_chunk, *args)
+                except RuntimeError:  # pool shut down mid-close: finish inline
+                    self._run_chunk(*args)
 
-    def _run_chunk(self, chunk: list, handler_of: dict, ticket_of: dict) -> None:
+    def _run_chunk(self, chunk: list, handler_of: dict, ticket_of: dict,
+                   chunk_id: int, t_drain: float) -> None:
         n = len(chunk)
-        for i, adm in chunk:
-            try:
-                reply = self._complete(
-                    adm, handler_of.get(i),
-                    batched=n > 1 and i in handler_of,
-                    batch_size=n if i in handler_of else 1,
-                )
-            except Exception as e:
-                reply = self._last_ditch(adm.q, e)
-            self._finish(ticket_of[i], reply)
+        t_start = self._clock()
+
+        def span_args() -> dict:
+            ms = 1e3 / n
+            return dict(
+                kind=chunk[0][1].q.kind, n=n,
+                lanes=self.request_bucket if handler_of else 1,
+                flush_wait_ms=ms * sum(t_drain - adm.t0 for _, adm in chunk),
+                wait_ms=ms * sum(t_start - adm.t0 for _, adm in chunk),
+                qids=[adm.q.qid for _, adm in chunk],
+            )
+
+        with instrument.span("dragon.service.chunk", span_args, chunk=chunk_id):
+            for i, adm in chunk:
+                try:
+                    reply = self._complete(
+                        adm, handler_of.get(i),
+                        batched=n > 1 and i in handler_of,
+                        batch_size=n if i in handler_of else 1,
+                    )
+                except Exception as e:
+                    reply = self._last_ditch(adm.q, e)
+                self._finish(ticket_of[i], reply)
 
     def _finish(self, ticket: int, reply: DesignReply) -> None:
         self._account(reply)

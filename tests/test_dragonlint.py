@@ -355,6 +355,29 @@ class TestStrayDebug:
             """
         assert not lint("src/repro/core/x.py", good)
 
+    def test_fires_on_span_under_trace(self):
+        bad = """
+            import jax
+            from repro.core import instrument
+
+            @jax.jit
+            def f(x):
+                with instrument.span("dragon.x"):
+                    return x + 1
+            """
+        assert "stray-debug" in names(lint("src/repro/core/x.py", bad))
+
+    def test_silent_on_span_around_dispatch(self):
+        good = """
+            import jax
+            from repro.core import instrument
+
+            def run(prog, x):
+                with instrument.span("dragon.session.launch", program="report"):
+                    return prog(x)
+            """
+        assert not lint("src/repro/core/x.py", good)
+
 
 class TestSwallowedFault:
     def test_fires_on_bare_except(self):

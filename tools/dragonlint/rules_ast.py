@@ -520,8 +520,8 @@ def retrace_hazard(rel: str, text: str, tree: ast.AST) -> Iterator[Finding]:
 
 @rule(
     "stray-debug",
-    doc="jax.debug.* / breakpoint() in engine modules (and print() under trace) "
-        "insert host callbacks into served programs",
+    doc="jax.debug.* / breakpoint() in engine modules (and print() or a profiler "
+        "span under trace) insert host callbacks into served programs or run once",
     scan=("src/repro/",),
 )
 def stray_debug(rel: str, text: str, tree: ast.AST) -> Iterator[Finding]:
@@ -543,6 +543,11 @@ def stray_debug(rel: str, text: str, tree: ast.AST) -> Iterator[Finding]:
                           "print() inside a traced region runs at trace time only "
                           "(or becomes a host callback) — use the driver loop or "
                           "jax.debug deliberately", _line(text, node.lineno))
+        elif d and d.endswith(("instrument.span", "TraceAnnotation")) and _in_traced(node, par, traced):
+            yield Finding("stray-debug", rel, node.lineno,
+                          f"{d}() inside a traced region records one span at trace "
+                          "time and nothing per call — open it in the host code "
+                          "around the dispatch", _line(text, node.lineno))
 
 
 # --------------------------------------------------------------------------- #
