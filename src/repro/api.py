@@ -390,10 +390,6 @@ def _arch_param_names() -> list[str]:
     return _ARCH_PARAM_NAMES
 
 
-def _flatten(tree) -> np.ndarray:
-    return np.concatenate([np.atleast_1d(np.asarray(x)) for x in jax.tree.leaves(tree)])
-
-
 _ELASTICITY_NAMES: tuple | None = None
 
 
@@ -410,25 +406,123 @@ def _attributed(rep: SimReport, objective: str, elast: np.ndarray) -> SimReport:
     return dataclasses.replace(rep, objective=objective, attribution=attribution)
 
 
-def _report_arrays(perfs: PerfEstimate, extras: dict) -> dict:
-    """The fields a :class:`SimReport` reads, as host arrays: one
-    device-to-host transfer per field (a no-op on arrays already fetched)."""
+# --------------------------------------------------------------------------- #
+# packed program outputs
+# --------------------------------------------------------------------------- #
+# Every served report and explain program returns its outputs packed: each
+# leaf raveled and concatenated into one flat buffer per dtype, per request
+# lane (batched programs return ``[nb, L]`` buffers, a lane per row).  The
+# host then fetches a dispatch's results in one device-to-host transfer per
+# dtype instead of one per leaf, and unpacks them as numpy views.
+#
+# Report programs also keep each leaf as an output of its own, never
+# fetched: where the concatenate is a leaf's only consumer, XLA fuses the
+# leaf's producers differently and a report moves by an ulp (seen on the
+# CPU); as outputs the leaves keep the unpacked program's bits, and the
+# packed copy holds them.  Explain programs pack with no such effect.
+
+
+@dataclass(frozen=True)
+class _Packing:
+    """Where each leaf of one lane's output tree lies in its packed
+    buffers: ``slots[i]`` is leaf ``i``'s ``(buffer, start, stop, shape)``,
+    buffers numbered by each dtype's first appearance in leaf order."""
+
+    treedef: object
+    slots: tuple
+
+    @staticmethod
+    def of(tree) -> "_Packing":
+        """The layout of ``tree``, whose leaves may be arrays, tracers or
+        ``jax.ShapeDtypeStruct``\\ s (``jax.eval_shape`` output)."""
+        leaves, treedef = jax.tree.flatten(tree)
+        buffer_of: dict = {}
+        ends: list[int] = []
+        slots = []
+        for leaf in leaves:
+            b = buffer_of.setdefault(np.dtype(leaf.dtype), len(buffer_of))
+            if b == len(ends):
+                ends.append(0)
+            start = ends[b]
+            ends[b] += math.prod(leaf.shape)
+            slots.append((b, start, ends[b], tuple(leaf.shape)))
+        return _Packing(treedef, tuple(slots))
+
+    def unpack(self, bufs):
+        """The output tree of one lane, as numpy views into its host
+        buffers (no copy)."""
+        return jax.tree.unflatten(
+            self.treedef, [bufs[b][s:e].reshape(shape) for b, s, e, shape in self.slots]
+        )
+
+
+def _pack(tree) -> tuple:
+    """``tree``'s leaves raveled and concatenated in leaf order, one buffer
+    per dtype, never cast — traced inside a served program, per lane."""
+    leaves = jax.tree.leaves(tree)
+    slots = _Packing.of(tree).slots
+    n = 1 + max(b for b, *_ in slots)
+    return tuple(
+        jnp.concatenate([jnp.ravel(x) for x, (b, *_) in zip(leaves, slots) if b == i])
+        for i in range(n)
+    )
+
+
+def _fetch(out) -> list:
+    """A packed program's buffers on the host: one device-to-host transfer
+    per buffer, recorded as the span's ``arrays``."""
+    with instrument.span("dragon.session.fetch", arrays=len(out)):
+        return [np.asarray(b) for b in out]
+
+
+def _report_lane(tech, arch, gstack, spec: ArchSpec, mcfg: MapperCfg):
+    """One request's report outputs, ``(PerfEstimate, extras)`` with a
+    leading ``[W]`` axis: simulate_breakdown computes both in one pass."""
+    return jax.vmap(lambda g: simulate_breakdown(tech, arch, g, spec, mcfg))(gstack)
+
+
+def _report_packed(tech, arch, gstack, spec: ArchSpec, mcfg: MapperCfg):
+    """A report program's lane: the packed buffers, then the leaves they
+    copy (outputs only to keep their bits; the host fetches the buffers)."""
+    out = _report_lane(tech, arch, gstack, spec, mcfg)
+    return _pack(out), out
+
+
+def _explain_lane(tech, arch, gstack, spec: ArchSpec, mcfg: MapperCfg, objective: str):
+    """One request's ``(g_tech, g_arch)``: d log(objective) / d log(param)."""
+
+    def loss(tz, az):
+        val, _ = stacked_log_objective(
+            from_log(tz), from_log(az), gstack, objective, spec=spec, mcfg=mcfg
+        )
+        return val
+
+    return jax.grad(loss, argnums=(0, 1))(to_log(tech), to_log(arch))
+
+
+_REPORT_PACKINGS: dict = {}  # report program key -> its lane's _Packing
+
+
+def _report_arrays(packing: _Packing, bufs) -> dict:
+    """The fields a :class:`SimReport` reads: one lane of a packed report
+    program's host buffers, unpacked by ``packing`` into numpy views."""
+    perfs, extras = packing.unpack(bufs)
     state = perfs.state
     return dict(
-        reads=np.asarray(state.reads),
-        writes=np.asarray(state.writes),
-        comp_ops=np.asarray(state.comp_ops),
-        bw_util=np.asarray(state.bw_util),
-        extras={k: np.asarray(v) for k, v in extras.items()},
-        runtime=np.asarray(perfs.runtime),
-        energy=np.asarray(perfs.energy),
-        power=np.asarray(perfs.power),
-        edp=np.asarray(perfs.edp),
-        cycles=np.asarray(perfs.cycles),
-        energy_mem=np.asarray(perfs.energy_mem),
-        energy_comp=np.asarray(perfs.energy_comp),
-        energy_leak=np.asarray(perfs.energy_leak),
-        area=np.asarray(perfs.area),
+        reads=state.reads,
+        writes=state.writes,
+        comp_ops=state.comp_ops,
+        bw_util=state.bw_util,
+        extras=extras,
+        runtime=perfs.runtime,
+        energy=perfs.energy,
+        power=perfs.power,
+        edp=perfs.edp,
+        cycles=perfs.cycles,
+        energy_mem=perfs.energy_mem,
+        energy_comp=perfs.energy_comp,
+        energy_leak=perfs.energy_leak,
+        area=perfs.area,
     )
 
 
@@ -586,15 +680,14 @@ class Session:
     def _report_spec(self, bucket, spec: ArchSpec, mcfg: MapperCfg):
         """One program for the whole report: batched PerfEstimate + the
         per-vertex / per-level breakdown extras (simulate_breakdown computes
-        both in one pass, so reports cost one compile and one dispatch)."""
+        both in one pass, so reports cost one compile and one dispatch),
+        packed so the host fetches them in one transfer per dtype."""
         tag = f"{self._tag}.report"
 
         def build():
             def fn(tech, arch, gstack):
                 instrument.count_trace(tag)
-                return jax.vmap(
-                    lambda g: simulate_breakdown(tech, arch, g, spec, mcfg)
-                )(gstack)
+                return _report_packed(tech, arch, gstack, spec, mcfg)
 
             return jax.jit(fn)
 
@@ -603,21 +696,28 @@ class Session:
     def _report_program(self, bucket, spec: ArchSpec, mcfg: MapperCfg):
         return self._program(*self._report_spec(bucket, spec, mcfg))
 
+    def _report_packing(self, w: Workload, a: Architecture, out, lead: int) -> _Packing:
+        """The lane layout of the report programs for ``w``'s bucket and
+        ``a``'s spec, read once per report program key from the shapes of
+        the leaves a report program ``out`` returns beside its buffers, less
+        ``lead`` request axes: no transfer and no second trace, and the
+        batched variants share it (each of their lanes is that report)."""
+        key = ("report", a.spec, self.mcfg, w.bucket)
+        packing = _REPORT_PACKINGS.get(key)
+        if packing is None:
+            lane = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[lead:], x.dtype), out[1])
+            packing = _REPORT_PACKINGS.setdefault(key, _Packing.of(lane))
+        return packing
+
     def _explain_spec(self, bucket, spec: ArchSpec, mcfg: MapperCfg, objective: str):
-        """Elasticities d log(objective) / d log(param) for tech AND arch."""
+        """Elasticities d log(objective) / d log(param) for tech AND arch,
+        packed: one vector, ``g_tech`` then ``g_arch`` in leaf order."""
         tag = f"{self._tag}.explain"
 
         def build():
             def fn(tech, arch, gstack):
                 instrument.count_trace(tag)
-
-                def loss(tz, az):
-                    val, _ = stacked_log_objective(
-                        from_log(tz), from_log(az), gstack, objective, spec=spec, mcfg=mcfg
-                    )
-                    return val
-
-                return jax.grad(loss, argnums=(0, 1))(to_log(tech), to_log(arch))
+                return _pack(_explain_lane(tech, arch, gstack, spec, mcfg, objective))
 
             return jax.jit(fn)
 
@@ -630,19 +730,17 @@ class Session:
     def _batched_report_spec(self, nb: int, bucket, spec: ArchSpec, mcfg: MapperCfg):
         """The report program with a leading *request* axis: one dispatch
         answers ``nb`` same-bucket queries, each with its own (tech, arch,
-        gstack).  Keyed by the request bucket too, so warm batches of
-        similar size never retrace."""
+        gstack), its packed buffers ``[nb, L]``, a lane per row.  Keyed by
+        the request bucket too, so warm batches of similar size never
+        retrace."""
         tag = f"{self._tag}.report_batched"
 
         def build():
-            def one(tech, arch, gstack):
-                return jax.vmap(
-                    lambda g: simulate_breakdown(tech, arch, g, spec, mcfg)
-                )(gstack)
-
             def fn(techs, archs, gstacks):
                 instrument.count_trace(tag)
-                return jax.vmap(one)(techs, archs, gstacks)
+                return jax.vmap(lambda t, a, g: _report_packed(t, a, g, spec, mcfg))(
+                    techs, archs, gstacks
+                )
 
             return jax.jit(fn)
 
@@ -654,22 +752,16 @@ class Session:
     def _batched_explain_spec(
         self, nb: int, bucket, spec: ArchSpec, mcfg: MapperCfg, objective: str
     ):
-        """Elasticities with a leading request axis (vmapped grad)."""
+        """Elasticities with a leading request axis (vmapped grad): one
+        ``[nb, L]`` buffer, a lane's vector per row."""
         tag = f"{self._tag}.explain_batched"
 
         def build():
-            def one(tech, arch, gstack):
-                def loss(tz, az):
-                    val, _ = stacked_log_objective(
-                        from_log(tz), from_log(az), gstack, objective, spec=spec, mcfg=mcfg
-                    )
-                    return val
-
-                return jax.grad(loss, argnums=(0, 1))(to_log(tech), to_log(arch))
-
             def fn(techs, archs, gstacks):
                 instrument.count_trace(tag)
-                return jax.vmap(one)(techs, archs, gstacks)
+                return jax.vmap(
+                    lambda t, a, g: _pack(_explain_lane(t, a, g, spec, mcfg, objective))
+                )(techs, archs, gstacks)
 
             return jax.jit(fn)
 
@@ -906,28 +998,20 @@ class Session:
     def _simulate_batch_assembled(self, ws, archs, nb, stacked) -> list[SimReport]:
         prog = self._batched_report_program(nb, ws[0].bucket, archs[0].spec, self.mcfg)
         with instrument.span("dragon.session.launch", program="report_batched"):
-            perfs, extras = prog(*stacked)
-        return self._reports_from_batch(ws, archs, perfs, extras)
+            out = prog(*stacked)
+        return self._reports_from_batch(ws, archs, out)
 
-    def _reports_from_batch(self, ws, archs, perfs, extras) -> list[SimReport]:
-        """Finish a batched report dispatch: slice the ``[nb]``-leading
-        program outputs back into per-lane :class:`SimReport`\\ s.  Shared by
-        :meth:`simulate_batch` and the serving pool's staging-buffer
-        dispatcher, so both paths build reports from identical bits."""
-        # one device->host sync for the whole batch, then numpy views per lane
-        with instrument.span("dragon.session.fetch"):
-            perfs = jax.tree.map(np.asarray, perfs)
-            extras = {k: np.asarray(v) for k, v in extras.items()}
+    def _reports_from_batch(self, ws, archs, out) -> list[SimReport]:
+        """Finish a batched report dispatch: fetch the packed ``[nb, L]``
+        buffers and build each lane's :class:`SimReport` from views of its
+        row.  Shared by :meth:`simulate_batch` and the serving pool's
+        staging-buffer dispatcher, so both paths build reports from
+        identical bits."""
+        packing = self._report_packing(ws[0], archs[0], out, lead=1)
+        host = _fetch(out[0])
         with instrument.span("dragon.session.report", lanes=len(ws)):
             return [
-                self._build_report(
-                    archs[i],
-                    ws[i],
-                    _report_arrays(
-                        jax.tree.map(lambda x: x[i], perfs),
-                        {k: v[i] for k, v in extras.items()},
-                    ),
-                )
+                self._build_report(archs[i], ws[i], _report_arrays(packing, [h[i] for h in host]))
                 for i in range(len(ws))
             ]
 
@@ -948,26 +1032,17 @@ class Session:
                 nb, ws[0].bucket, archs[0].spec, self.mcfg, objective
             )
             with instrument.span("dragon.session.launch", program="explain_batched"):
-                g_techs, g_archs = prog(*stacked)
-            return self._attribute_batch(reports, g_techs, g_archs, objective)
+                out = prog(*stacked)
+            return self._attribute_batch(reports, out, objective)
 
-    def _attribute_batch(self, reports, g_techs, g_archs, objective) -> list[SimReport]:
-        """Finish a batched explain dispatch: rank the ``[nb]``-leading
-        gradient outputs into per-lane attributions.  Shared by
-        :meth:`explain_batch` and the serving pool's staging-buffer
-        dispatcher."""
-        with instrument.span("dragon.session.fetch"):
-            g_techs = jax.tree.map(np.asarray, g_techs)
-            g_archs = jax.tree.map(np.asarray, g_archs)
+    def _attribute_batch(self, reports, out, objective) -> list[SimReport]:
+        """Finish a batched explain dispatch: fetch the ``[nb, L]``
+        elasticity buffer and rank each lane's row into its attribution.
+        Shared by :meth:`explain_batch` and the serving pool's
+        staging-buffer dispatcher."""
+        (elast,) = _fetch(out)  # float32 parameters: one buffer
         with instrument.span("dragon.session.attribute", lanes=len(reports)):
-            out = []
-            for i, rep in enumerate(reports):
-                elast = np.concatenate([
-                    _flatten(jax.tree.map(lambda x: x[i], g_techs)),
-                    _flatten(jax.tree.map(lambda x: x[i], g_archs)),
-                ])
-                out.append(_attributed(rep, objective, elast))
-            return out
+            return [_attributed(rep, objective, elast[i]) for i, rep in enumerate(reports)]
 
     # ------------------------------------------------------------ simulate --
     def perf(self, workload, *, architecture=None) -> PerfEstimate:
@@ -985,11 +1060,11 @@ class Session:
         with instrument.span("dragon.session.simulate", bucket=w.bucket, n=1):
             prog = self._report_program(w.bucket, a.spec, self.mcfg)
             with instrument.span("dragon.session.launch", program="report"):
-                perfs, extras = prog(a.tech, a.arch, w.stacked)
-            with instrument.span("dragon.session.fetch"):
-                arrays = _report_arrays(perfs, extras)
+                out = prog(a.tech, a.arch, w.stacked)
+            host = _fetch(out[0])
             with instrument.span("dragon.session.report", lanes=1):
-                return self._build_report(a, w, arrays)
+                packing = self._report_packing(w, a, out, lead=0)
+                return self._build_report(a, w, _report_arrays(packing, host))
 
     def explain(self, workload, *, objective: str = "edp", architecture=None) -> SimReport:
         """:meth:`simulate` + gradient-based bottleneck attribution: every
@@ -1001,9 +1076,8 @@ class Session:
             rep = self.simulate(w, architecture=a)
             prog = self._explain_program(w.bucket, a.spec, self.mcfg, objective)
             with instrument.span("dragon.session.launch", program="explain"):
-                g_tech, g_arch = prog(a.tech, a.arch, w.stacked)
-            with instrument.span("dragon.session.fetch"):
-                elast = np.concatenate([_flatten(g_tech), _flatten(g_arch)])
+                out = prog(a.tech, a.arch, w.stacked)
+            (elast,) = _fetch(out)  # float32 parameters: one buffer
             with instrument.span("dragon.session.attribute", lanes=1):
                 return _attributed(rep, objective, elast)
 
@@ -1179,13 +1253,7 @@ class Session:
         out["simulate"] = jax.make_jaxpr(sim)(a.tech, a.arch, gstack)
 
         def expl(tech, arch, g):
-            def loss(tz, az):
-                val, _ = stacked_log_objective(
-                    from_log(tz), from_log(az), g, objective, spec=spec, mcfg=mcfg
-                )
-                return val
-
-            return jax.grad(loss, argnums=(0, 1))(to_log(tech), to_log(arch))
+            return _explain_lane(tech, arch, g, spec, mcfg, objective)
 
         out["explain"] = jax.make_jaxpr(expl)(a.tech, a.arch, gstack)
 
@@ -1234,7 +1302,7 @@ class Session:
     # -------------------------------------------------------------- report --
     def _build_report(self, a: Architecture, w: Workload, arrays: dict) -> SimReport:
         """A :class:`SimReport` from the host arrays :func:`_report_arrays`
-        fetched."""
+        unpacked."""
         reads, writes = arrays["reads"], arrays["writes"]
         comp_ops, bw_util = arrays["comp_ops"], arrays["bw_util"]
         runtime, energy, power = arrays["runtime"], arrays["energy"], arrays["power"]

@@ -63,7 +63,9 @@ __all__ = [
     "canonical_key_text",
 ]
 
-SCHEMA_VERSION = 1
+# 2: report and explain programs return packed buffers, so an executable
+# keyed as before returns another output tree
+SCHEMA_VERSION = 2
 
 _MAGIC = b"DRGNAOT\x01"
 _SUFFIX = ".aotx"

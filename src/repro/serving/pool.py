@@ -181,8 +181,8 @@ class StagedBatchingService(BatchingDesignService):
             staged = self._assembler.stage(ws, archs)
         prog = sess._batched_report_program(self.request_bucket, bucket, spec, sess.mcfg)
         with instrument.span("dragon.session.launch", program="report_batched"):
-            perfs, extras = prog(*staged)
-        reports = sess._reports_from_batch(ws, archs, perfs, extras)
+            out = prog(*staged)
+        reports = sess._reports_from_batch(ws, archs, out)
         if kind == "simulate":
             return reports
         objective = adms[0].q.objective
@@ -190,8 +190,8 @@ class StagedBatchingService(BatchingDesignService):
             self.request_bucket, bucket, spec, sess.mcfg, objective
         )
         with instrument.span("dragon.session.launch", program="explain_batched"):
-            g_techs, g_archs = eprog(*staged)
-        return sess._attribute_batch(reports, g_techs, g_archs, objective)
+            out = eprog(*staged)
+        return sess._attribute_batch(reports, out, objective)
 
 
 # --------------------------------------------------------------------------- #

@@ -286,6 +286,26 @@ def _corrupt(path: str, mode: str) -> None:
 class TestCorruptionRobustness:
     MODES = ("truncate", "zero_length", "bit_flip", "garbage")
 
+    def test_entry_of_another_schema_is_a_clean_miss(self, tmp_path, preheated, monkeypatch):
+        """An entry written under an older schema (programs whose outputs
+        had another tree) is never deserialized: construction rejects it,
+        leaves it in place, and the session compiles afresh."""
+        d = _copy_cache(preheated, tmp_path)
+        entry = _entry_path(d)
+        monkeypatch.setattr(aotcache, "SCHEMA_VERSION", aotcache.SCHEMA_VERSION + 1)
+
+        def refuse(blob):
+            raise AssertionError("deserialized an entry of another schema")
+
+        monkeypatch.setattr(runtime, "deserialize_compiled", refuse)
+        sess = Session("base", cache_dir=d)
+        assert sess.disk_loaded == 0 and sess.programs == {}
+        assert sess._aot.stats()["rejected"] == 1 and sess._aot.stats()["quarantined"] == 0
+        key, _ = sess._report_spec((1, 32), sess.architecture.spec, sess.mcfg)
+        assert sess._aot.get(key) is None
+        assert os.path.exists(entry)
+        assert sess.simulate("lstm").to_json() == preheated["ref"]
+
     @pytest.mark.parametrize("mode", MODES)
     def test_corrupt_entry_quarantined_and_recompiled(self, mode, tmp_path, preheated):
         d = _copy_cache(preheated, tmp_path)

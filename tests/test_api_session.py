@@ -9,11 +9,14 @@ repro.core.instrument, not inferred from wall time), and a changed
 objective mix / design point must reuse the compiled program (weights and
 parameters are traced arguments).
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.api import Architecture, Session, Workload
 from repro.core import instrument
 from repro.core.dhdl import load_arch, parse_arch
@@ -24,6 +27,7 @@ from repro.core.mapper import MapperCfg
 from repro.core.params import ArchParams, ArchSpec, TechParams
 from repro.core.popsim import pareto_dse
 from repro.workloads import get_workload
+from tests._per_leaf import per_leaf_replies
 
 
 # --------------------------------------------------------------------------- #
@@ -211,6 +215,76 @@ class TestParity:
         parsed = json.loads(rep.to_json())
         assert parsed["architecture"] == "edge"
         assert len(parsed["workloads"]) == 2
+
+
+# --------------------------------------------------------------------------- #
+# packed program outputs against the per-leaf path
+# --------------------------------------------------------------------------- #
+
+
+_MIXED = (["lstm", "merge_sort", "dlrm", "gcn"], ["base", "edge", "datacenter", "base"])
+
+
+class TestPackedOutputs:
+    @pytest.mark.parametrize("kind", ["report", "explain", "report_batched", "explain_batched"])
+    def test_every_program_returns_one_array_per_dtype(self, kind):
+        sess = Session("edge")
+        w = Workload(["lstm", "bert_base"])
+        a = sess.architecture
+        bucket, spec, mcfg = w.bucket, a.spec, sess.mcfg
+        args = (a.tech, a.arch, w.stacked)
+        if kind.endswith("_batched"):
+            args = jax.tree.map(lambda x: jnp.stack([x] * 4), args)
+        key, build = {
+            "report": lambda: sess._report_spec(bucket, spec, mcfg),
+            "explain": lambda: sess._explain_spec(bucket, spec, mcfg, "edp"),
+            "report_batched": lambda: sess._batched_report_spec(4, bucket, spec, mcfg),
+            "explain_batched": lambda: sess._batched_explain_spec(4, bucket, spec, mcfg, "edp"),
+        }[kind]()
+        assert key[0] == kind
+        out = jax.eval_shape(build(), *args)
+        lead = (4,) if kind.endswith("_batched") else ()
+        if kind.startswith("report"):
+            out, leaves = out  # the packed buffers, then the leaves they copy
+            width = sum(math.prod(x.shape[len(lead):]) for x in jax.tree.leaves(leaves))
+        else:
+            width = len(sess.explain("lstm").attribution)
+        assert isinstance(out, tuple) and len(out) == 1  # every output is float32
+        (buf,) = out
+        assert buf.dtype == jnp.float32 and buf.shape == lead + (width,)
+
+    def test_packing_keeps_each_dtype_and_shape(self):
+        tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+                "b": jnp.array([7, -8], jnp.int32),
+                "c": jnp.float32(0.5),
+                "d": jnp.array([2**24 + 1], jnp.int32)}
+        bufs = jax.jit(api._pack)(tree)
+        assert [b.dtype for b in bufs] == [jnp.float32, jnp.int32]
+        got = api._Packing.of(tree).unpack([np.asarray(b) for b in bufs])
+        for k in tree:
+            assert got[k].dtype == tree[k].dtype and got[k].shape == tree[k].shape
+            assert np.array_equal(got[k], np.asarray(tree[k])), k
+
+    def test_simulate_and_explain_bit_identical_to_per_leaf_path(self):
+        sess = Session("base")
+        ws, archs = _MIXED
+        for kind in ("simulate", "explain"):
+            want = per_leaf_replies(sess, ws, archs, kind=kind)
+            got = [getattr(sess, kind)(w, architecture=a) for w, a in zip(ws, archs)]
+            assert [r.to_json() for r in got] == [r.to_json() for r in want], kind
+
+    @pytest.mark.parametrize("kind", ["simulate", "explain"])
+    def test_batches_bit_identical_to_per_leaf_path(self, kind):
+        sess = Session("base")
+        ws, archs = _MIXED
+        call = sess.simulate_batch if kind == "simulate" else sess.explain_batch
+        for nb in (4, 8):
+            want = per_leaf_replies(sess, ws, archs, kind=kind, request_bucket=nb)
+            got = call(ws, architectures=archs, request_bucket=nb)
+            assert [r.to_json() for r in got] == [r.to_json() for r in want], nb
+            # a lane's reply does not depend on the batch around it
+            lone = call(ws[1:2], architectures=archs[1:2], request_bucket=nb)
+            assert lone[0].to_json() == want[1].to_json()
 
 
 # --------------------------------------------------------------------------- #

@@ -27,6 +27,7 @@ from repro.serving import (
     StagedBatchingService,
 )
 from repro.serving import protocol
+from tests._per_leaf import per_leaf_replies
 
 POLICY = FlushPolicy(max_batch=8, max_delay_s=0.001)
 
@@ -135,6 +136,20 @@ class TestStagedAssembly:
     def test_staged_replies_bit_identical_to_sequential(self, baseline):
         qs, want = baseline
         got = _fingerprints(_mk(StagedBatchingService).serve(qs))
+        assert got == want
+
+    def test_staged_replies_bit_identical_to_per_leaf_path(self, baseline):
+        """The staged dispatcher hands the packed programs' outputs to the
+        session unchanged; each reply equals the unpacked program's, fetched
+        leaf by leaf, at the service's request bucket."""
+        qs, _ = baseline
+        svc = _mk(StagedBatchingService)
+        got = _fingerprints(svc.serve(qs))
+        want = []
+        for q in qs:
+            (rep,) = per_leaf_replies(svc.session, [q.workload], [q.architecture], kind=q.kind,
+                                      objective=q.objective, request_bucket=svc.request_bucket)
+            want.append(json.dumps(rep.to_json(), sort_keys=True))
         assert got == want
 
     def test_singleton_queries_route_through_staged_dispatch(self, baseline):

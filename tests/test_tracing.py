@@ -168,6 +168,12 @@ class TestRecorded:
         sims = [e for e in events if e["name"] == "dragon.session.simulate" and _inside(e, opt[0])]
         assert len(sims) == 2
 
+    def test_each_fetch_moves_one_packed_array(self, recorded):
+        """Report and explain programs return one float32 buffer each: every
+        fetch, batched or not, transfers one device array."""
+        fetches = [e for e in recorded["events"] if e["name"] == "dragon.session.fetch"]
+        assert fetches and all(e["args"]["arrays"] == 1 for e in fetches)
+
     def test_chunk_id_is_handed_to_nested_spans_only(self, recorded):
         by_name = {e["name"]: e for e in recorded["events"] if e["name"].startswith("dragon.test.")}
         assert by_name["dragon.test.inner"]["args"] == {"lanes": 3, "chunk": 77}
