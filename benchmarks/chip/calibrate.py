@@ -87,6 +87,8 @@ def main(argv=None) -> int:
     cell, mix = st["cell"], st["mix"]
     out = Path(a.out)
     out.mkdir(parents=True, exist_ok=True)
+    import check as C
+
     rows = []
     for i, seed in enumerate(seeds):
         if rates:
@@ -112,15 +114,14 @@ def main(argv=None) -> int:
         for m in run.metrics_for(st["bench"], a.workload, "end_to_end"):
             if m["name"] != "setup_s":
                 row[m["name"]] = run.read_metric(m, ctx)
-        row["pairs"] = run.sample(win["records"], a.sample or mix["check"]["sample"], seed)
+        row["pairs"] = [(q, C.host_result(q, r))
+                        for q, r in run.sample(win["records"], a.sample or mix["check"]["sample"], seed)]
         rows.append(row)
         print("window", json.dumps({k: v for k, v in row.items() if k != "pairs"}), flush=True)
     every = []
     if a.exhaustive:
         every = run_every(st)
         print(f"exhaustive: {len(every)} queries", flush=True)
-    import check as C
-
     inputs = run.free_program(st)
     with run.on_host():
         for row in rows:

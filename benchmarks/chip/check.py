@@ -21,6 +21,34 @@ Numbers compared, each the widest gap over the sampled replies of a run:
   |log program - log reference| over the parameters that the reference
   moves (see ``moved``).
 
+A frontier reply (``Session.frontier``, the population DSE) is judged on:
+
+* ``history_gap`` -- a seeded sample of members (``frontier_members``): each
+  member's median, over the epochs, of the widest gap in its per-epoch
+  ``[value, log metrics]`` row against the reference's own descent; the
+  widest over the members.  A median, because the metrics step at the
+  model's ceilings: where a member's path runs along a step, float32 and
+  float64 put it on either side for an epoch, and its row then jumps by
+  the step (0.37 in log time, once in 96 sampled calls; ``PERF.md``);
+* ``member_gap`` -- those members' final log-space parameters against the
+  reference's, over the parameters the reference moves (``moved``);
+* ``final_gap`` -- those members' final log metrics, as the reply states
+  them, against the reference's evaluation at the member's final design in
+  the reply, as the widest |log| gap (a ceiling that ties there takes the
+  program's side, as at the start design);
+* ``front_mismatch`` -- the members whose place on the front (the reply's
+  front and its feasible count) differs from the plain filter's over the
+  program's own final metrics; a member whose area or power lies within
+  ``TIE_REL`` of its budget limit keeps the program's feasibility;
+* ``hv_rel`` -- the reply's hypervolume against the plain one of that
+  front, in the box the call asked for, as a relative gap;
+* ``winner_gap`` -- each winner's ``.dhd`` text against that member's final
+  design in the reply, as the widest |log| gap (float32 as the program
+  holds it: exact).
+
+The last three hold the program to its own final metrics and designs, so
+the control (below) gives them no reading: its precision cannot move them.
+
 An optimize reply's two reports count in ``report_rel`` and ``vertex_gap``:
 the baseline at the start design, and the optimized report at the final
 design the reply states.  Where the argument of one of a vertex's ceilings
@@ -38,6 +66,7 @@ control on the chip (``PERF.md`` gives them).
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 from functools import lru_cache
 from pathlib import Path
@@ -307,6 +336,202 @@ def design_gap(program: tuple, descent: dict, mask: dict) -> float:
     return worst
 
 
+FRONTIER_MEMBERS = 16  # members of a frontier reply the reference follows
+
+
+def host_result(q, res):
+    """The reply as the check reads it, all on the host: a frontier reply's
+    final population (device arrays) is copied to numpy, by field name, so
+    the reference can run once the program's state is freed.  Other
+    replies are returned as they are."""
+    if q.kind != "frontier":
+        return res
+    fields = lambda tree: {f.name: np.asarray(getattr(tree, f.name), np.float64)
+                           for f in dataclasses.fields(tree)}
+    raw = dataclasses.replace(res.raw, tech=fields(res.raw.tech), arch=fields(res.raw.arch))
+    return dataclasses.replace(res, raw=raw)
+
+
+def frontier_members(q, n: int = FRONTIER_MEMBERS) -> np.ndarray:
+    """The members of a frontier call that the reference follows: the first
+    member of each seed design (the library design itself), then members
+    drawn from the call's key, its place in the window and the run's seed
+    (``Query.draw``), so that runs of one call follow different members."""
+    k, p = len(q.call["seeds"]), int(q.call["population"])
+    rest = np.random.default_rng([int(q.call["key"]), q.draw, q.qid, 1]).choice(
+        np.arange(k, p), size=min(n - k, p - k), replace=False)
+    return np.sort(np.concatenate([np.arange(k), rest]))
+
+
+class Library:
+    """A seed design by its library name, as ``Inputs.design`` takes it."""
+
+    def __init__(self, name: str):
+        self.base, self.param, self.value = name, None, None
+
+
+def reference_frontier(inputs: Inputs, q, members: np.ndarray, dtype_name: str = "float64",
+                       start_rows: np.ndarray | None = None) -> dict:
+    """The reference's descent of ``members`` of a frontier call: the
+    population seeded from the configuration's library designs and the
+    call's key, each member's objective mix, and the budget penalty's
+    geometric ramp, as the call states them.  With ``start_rows`` (the
+    program's first-epoch ``[value, log metrics]`` row of each member), a
+    ceiling that ties at a member's start design (``start_ties``) takes the
+    side nearer the program's row."""
+    import jax
+
+    c = q.call
+    designs = [inputs.design(Library(s)) for s in c["seeds"]]
+    mem_type = designs[0][2]
+    pop = int(c["population"])
+    k_seed, k_mix = jax.random.split(jax.random.PRNGKey(int(c["key"])))
+    tech, arch = R.seed_population([d[:2] for d in designs], pop, k_seed, float(c["sigma"]), np.float64)
+    tech, arch = ({k: np.asarray(v, np.float64)[members] for k, v in t.items()} for t in (tech, arch))
+    weights = R.sample_objective_mixes(pop, c["metrics"], k_mix, float(c["concentration"]))[members]
+    w0, w1 = c["penalty_weight"]
+    ramp = np.geomspace(max(w0, 1e-6), max(w1, 1e-6), int(c["steps"]))
+    graph = inputs.graphs[q.graph]
+    ups = np.stack([graph["tie_up"]] * len(members))
+    if start_rows is not None:
+        for j in range(len(members)):
+            ups[j], _ = start_ties(_member(tech, j), _member(arch, j), mem_type, graph, start_rows[j, 1:])
+    f32 = lambda x: float(np.float32(x))
+    fn = _frontier_descend(mem_type, dtype_name, float(c["lr"]))
+    out = fn(_cast(tech, "float64"), _cast(arch, "float64"), _cast(dict(graph, tie_up=ups), "float64"),
+             weights, f32(c["area_budget"]), f32(c["power_budget"]), ramp)
+    return jax_tree_f64(out)
+
+
+def start_ties(tech: dict, arch: dict, mem_type: tuple, graph: dict, logm: np.ndarray) -> tuple:
+    """A member's ``tie_up`` rows: where a ceiling ties at the design
+    (``reference.ceil_tie``), the choice, made jointly for each kind of
+    ceiling, whose log metrics lie nearest the program's ``logm``; -1
+    elsewhere.  And the widest |log| gap of those metrics to ``logm``.  The
+    program starts from ``exp(log(x))`` in float32, which lands on either
+    side of a design's whole-number quotients."""
+    ties = np.asarray(_sim(mem_type, "float64")(_cast(tech, "float64"), _cast(arch, "float64"),
+                                                _cast(graph, "float64"))["ties_v"]) > 0
+    kinds = [j for j in range(ties.shape[1]) if ties[:, j].any()]
+    tz = _cast({k: np.log(v) for k, v in tech.items()}, "float64")
+    az = _cast({k: np.log(v) for k, v in arch.items()}, "float64")
+    best, best_gap = -np.ones(ties.shape), float("inf")
+    for bits in itertools.product((0.0, 1.0), repeat=len(kinds)):
+        up = -np.ones(ties.shape)
+        for j, bit in zip(kinds, bits):
+            up[:, j] = np.where(ties[:, j], bit, -1.0)
+        got = _start_metrics(mem_type)(tz, az, _cast(dict(graph, tie_up=up), "float64"))
+        gap = float(np.max(np.abs(np.asarray(got, np.float64) - logm)))
+        if gap < best_gap:
+            best, best_gap = up, gap
+    return best, best_gap
+
+
+@lru_cache(maxsize=None)
+def _start_metrics(mem_type: tuple):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda tz, az, g: R.member_metrics(tz, az, mem_type, g, jnp.float64)[0])
+
+
+@lru_cache(maxsize=None)
+def _frontier_descend(mem_type: tuple, dtype_name: str, lr: float):
+    import jax
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(dtype_name)
+    one = lambda t, a, g, w, ab, pb, s: R.member_descend(t, a, mem_type, g, w, ab, pb, s, lr, dt)
+    graph_axes = {k: 0 if k == "tie_up" else None for k in ("n_comp", "n_read", "n_write", "n_alloc",
+                                                            "dims", "tie_up")}
+    return jax.jit(jax.vmap(one, in_axes=(0, 0, graph_axes, 0, None, None, None)))
+
+
+def _member(tree: dict, j: int) -> dict:
+    return {k: v[j] for k, v in tree.items()}
+
+
+def member_gaps(prog_hist: np.ndarray, prog_final: tuple, prog_logm: np.ndarray, ref: dict,
+                mem_type: tuple, graph: dict) -> dict:
+    """``history_gap``, ``member_gap`` and ``final_gap`` of followed members:
+    the program's ``[epoch, member, 5]`` rows, final ``(tech, arch)`` values
+    and final log metrics against the reference's descent
+    (``reference_frontier``) and its evaluation at those final designs."""
+    hist = np.swapaxes(np.asarray(ref["history"], np.float64), 0, 1)  # [epoch, member, 5]
+    if prog_hist.shape == hist.shape:
+        hgap = float(np.max(np.median(np.max(np.abs(prog_hist - hist), axis=2), axis=0)))
+    else:
+        hgap = float("inf")
+    mgap = fgap = 0.0
+    for j in range(hist.shape[1]):
+        descent = {"grad": {s: _member(ref["grad"][s], j) for s in ("tech", "arch")},
+                   "tech": {k: np.exp(v) for k, v in _member(ref["tech"], j).items()},
+                   "arch": {k: np.exp(v) for k, v in _member(ref["arch"], j).items()}}
+        final = tuple(_member(t, j) for t in prog_final)
+        mgap = max(mgap, design_gap(final, descent, moved(descent)))
+        fgap = max(fgap, start_ties(*final, mem_type, graph, np.asarray(prog_logm[j], np.float64))[1])
+    return {"history_gap": hgap if np.isfinite(hgap) else float("inf"), "member_gap": mgap,
+            "final_gap": fgap if np.isfinite(fgap) else float("inf")}
+
+
+def frontier_readings(inputs: Inputs, q, res) -> dict:
+    """The numbers compared for one frontier reply (``host_result``)."""
+    c, raw = q.call, res.raw
+    members = frontier_members(q)
+    hist = np.asarray(raw.history, np.float64)[:, members]
+    ref = reference_frontier(inputs, q, members, start_rows=hist[0])
+    final = tuple({k: v[members] for k, v in tree.items()} for tree in (raw.tech, raw.arch))
+    out = member_gaps(hist, final, np.asarray(raw.log_metrics, np.float64)[members], ref,
+                      inputs.design(Library(c["seeds"][0]))[2], inputs.graphs[q.graph])
+
+    # the front: the plain filter over the program's own final metrics
+    cols, (lim_a, lim_p), (lo, hi) = _frontier_terms(c)
+    logm = np.asarray(raw.log_metrics, np.float64)
+    area, power = np.asarray(raw.area, np.float64), np.asarray(raw.power, np.float64)
+    feasible = np.isfinite(logm).all(axis=1) & (area <= lim_a) & (power <= lim_p)
+    tie = (np.abs(area - lim_a) <= R.TIE_REL * lim_a) | (np.abs(power - lim_p) <= R.TIE_REL * lim_p)
+    feasible = np.where(tie, np.asarray(raw.feasible, bool), feasible)
+    front = R.non_dominated(logm[:, cols], feasible)
+    shown = {int(p.index) for p in res.front}
+    out["front_mismatch"] = (len(shown ^ set(np.nonzero(front)[0].tolist()))
+                             + abs(int(res.feasible) - int(feasible.sum())))
+
+    # the hypervolume of that front, in the box the call asked for; a sample
+    # within TIE_REL of a point's coordinate may count either way
+    least, most = R.hypervolume(logm[front][:, cols], lo, hi, R.TIE_REL) if front.any() else (0.0, 0.0)
+    hv = float(res.hypervolume)
+    out["hv_rel"] = float(rel(hv, min(max(hv, least), most)))
+
+    # each winner as the reply states it: its .dhd and its headline numbers
+    worst = 0.0
+    for p in res.front:
+        i = int(p.index)
+        tech, arch = parse_dhd(p.dhd)
+        for got, want in ((tech, raw.tech), (arch, raw.arch)):
+            if set(got) != set(want):
+                return dict(out, winner_gap=float("inf"))
+            for k, v in got.items():
+                g = np.asarray(np.float32(v), np.float64)
+                worst = max(worst, float(np.max(np.abs(np.log(g) - np.log(want[k][i])))))
+        shown_m = (p.time_s, p.energy_j, p.edp, p.area_mm2, p.power_w)
+        want_m = (np.exp(raw.log_metrics[i, 0]), np.exp(raw.log_metrics[i, 1]),
+                  np.exp(raw.log_metrics[i, 3]), raw.area[i], raw.power[i])
+        for a, b in zip(shown_m, want_m):
+            worst = max(worst, abs(float(np.log(a)) - float(np.log(np.float64(b)))))
+    out["winner_gap"] = worst
+    return out
+
+
+def _frontier_terms(c: dict) -> tuple:
+    """A frontier call's metric columns (``PARETO_METRICS`` indices), its
+    area and power limits (the budgets, float32 as the program holds them,
+    times 1 + ``budget_tol``) and its hypervolume box (float32)."""
+    tol = 1.0 + float(c["budget_tol"])
+    return ([R.PARETO_METRICS.index(m) for m in c["metrics"]],
+            (np.float32(c["area_budget"]) * tol, np.float32(c["power_budget"]) * tol),
+            tuple(np.asarray(np.asarray(b, np.float32), np.float64) for b in c["hv_box"]))
+
+
 def _widest(out: dict, key: str, value: float) -> None:
     out[key] = max(out.get(key, 0.0), value if np.isfinite(value) else float("inf"))
 
@@ -315,6 +540,13 @@ def program_readings(inputs: Inputs, pairs: list) -> dict:
     """The numbers compared, over ``pairs`` of (query, program result)."""
     out = {}
     for q, res in pairs:
+        if q.kind == "frontier":
+            for k, v in frontier_readings(inputs, q, res).items():
+                if k == "front_mismatch":
+                    out[k] = out.get(k, 0) + v
+                else:
+                    _widest(out, k, v)
+            continue
         rep = res.baseline if q.kind == "optimize" else res
         view = report_view(rep)
         ref, graph = tie_aware_report(inputs, q, view)
@@ -348,6 +580,17 @@ def control_readings(inputs: Inputs, queries: list, dtype_name: str = "bfloat16"
     place (the control that the limits must refuse)."""
     out = {}
     for q in queries:
+        if q.kind == "frontier":
+            members = frontier_members(q)
+            lo, hi = reference_frontier(inputs, q, members, dtype_name), reference_frontier(inputs, q, members)
+            final = tuple({k: np.exp(v) for k, v in lo[s].items()} for s in ("tech", "arch"))
+            gaps = member_gaps(np.swapaxes(lo["history"], 0, 1), final, lo["log_metrics"], hi,
+                               inputs.design(Library(q.call["seeds"][0]))[2], inputs.graphs[q.graph])
+            for k, v in gaps.items():
+                _widest(out, k, v)
+            # hv_rel, front_mismatch and winner_gap hold the program to its
+            # own final metrics; the control's precision cannot move them
+            continue
         ref = reference_report(inputs, q)
         low = reference_report(inputs, q, dtype_name)
         view = {k: (float(v) if np.ndim(v) == 0 else np.asarray(v, np.float64)) for k, v in low.items()}

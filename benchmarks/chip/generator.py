@@ -9,7 +9,13 @@ and of (graph, architecture) pairs, and the exponential gaps of each block of
 So seeds reorder the work and the gaps instead of changing them.
 Closed-loop mixes give an endless stream the client takes from until the
 window ends.  A query's keyword arguments come from the mix's ``call`` entry
-for its kind, so the kind names the ``Session`` call and nothing else does.
+for its kind, so the kind names the ``Session`` call and nothing else does;
+a mix may add arguments per graph (``per_graph``) and a pool of ``keys``:
+each graph's calls then take every key of the pool once per round, in an
+order drawn from the seed, so every seed sends the same calls.  A mix whose
+window holds only a few calls fixes the graph it starts from
+(``first_graph``), so that every window holds the same ones; a keyed mix's
+seed still draws which members of each reply the check follows (``draw``).
 """
 from __future__ import annotations
 
@@ -34,11 +40,12 @@ class Design:
 @dataclass
 class Query:
     qid: int
-    kind: str  # simulate | explain | optimize
+    kind: str  # simulate | explain | optimize | frontier
     graph: str  # a graph name of the configuration
     design: Design
     due_s: float | None = None  # open loop: seconds after the window opens
     call: dict = field(default_factory=dict)  # extra keyword arguments of the call
+    draw: int = 0  # drawn from the seed: which members of a frontier reply the check follows
 
 
 BLOCK = 5  # closed-loop calls per graph in one shuffled block of work
@@ -80,6 +87,24 @@ def sweep_values(config: dict, base: str, param: str, points: int, span) -> list
 def call_of(mix: dict, kind: str) -> dict:
     """The keyword arguments the mix gives every call of ``kind``."""
     return dict(mix.get("call", {}).get(kind, {}))
+
+
+def call_for(mix: dict, kind: str, graph: str, key: int | None = None) -> dict:
+    """The keyword arguments of one call of ``kind`` on ``graph``: the
+    mix's for every such call, its ``per_graph`` ones for that graph, and
+    ``key`` where one is given."""
+    call = call_of(mix, kind)
+    call.update(mix.get("per_graph", {}).get(graph, {}))
+    if key is not None:
+        call["key"] = key
+    return call
+
+
+def calls_of(mix: dict, config: dict) -> list[tuple[str, str, dict]]:
+    """Every distinct (kind, graph, call) a closed-loop mix with a key pool
+    sends, whatever the seed: what its warm-up has to run."""
+    return [(k, g["name"], call_for(mix, k, g["name"], key))
+            for k in mix["kinds"] for g in config["graphs"] for key in mix["keys"]]
 
 
 def arrival_times(mix: dict, seed: int, seconds: float) -> np.ndarray:
@@ -149,7 +174,12 @@ def closed_stream(mix: dict, config: dict, seed: int):
             p = perturb["params"][rng.integers(len(perturb["params"]))]
             u = rng.uniform(-perturb["log_span"], perturb["log_span"])
             designs.append(Design(base, p, f32(base_value(config, base, p) * math.exp(u))))
-    first = int(rng.integers(len(graphs)))
+    # a mix may fix the graph it starts from, so that every seed's window
+    # holds the same calls when it holds only a few
+    first = graphs.index(mix["first_graph"]) if "first_graph" in mix else int(rng.integers(len(graphs)))
+    pool = mix.get("keys")
+    draw = int(rng_for(seed, stream=5).integers(2**62)) if pool else 0
+    key_order, rounds = rng_for(seed, stream=4), {g: [] for g in graphs}
     # the work comes in shuffled blocks that each hold every (kind, graph) in
     # the mix's shares, so every seed sends the same work per block
     per_graph = [k for k, c in split_counts(BLOCK, mix["kinds"]).items() for _ in range(c)]
@@ -170,7 +200,12 @@ def closed_stream(mix: dict, config: dict, seed: int):
             design = designs[qid % len(designs)]
         else:
             design = Design(base)
-        yield Query(qid, kind, g, design, call=call_of(mix, kind))
+        key = None
+        if pool:
+            if not rounds[g]:
+                rounds[g] = [pool[i] for i in key_order.permutation(len(pool))]
+            key = rounds[g].pop()
+        yield Query(qid, kind, g, design, call=call_for(mix, kind, g, key), draw=draw)
 
 
 def designs_of(mix: dict, config: dict, seed: int, seconds: float) -> set:

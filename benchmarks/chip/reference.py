@@ -417,3 +417,172 @@ def descend(tech: dict, arch: dict, mem_type, graph: dict, objective: str, steps
     state, vals = jax.lax.scan(epoch, state, jnp.arange(steps))
     return dict(objective=vals, tech=from_log(state[0], ta), arch=from_log(state[1], aa),
                 grad=dict(tech=gt, arch=ga))
+
+
+# --------------------------------------------------------------------------- #
+# population DSE (Session.frontier): seeding, mixes, member epochs, front, HV
+# --------------------------------------------------------------------------- #
+
+PARETO_METRICS = ("time", "energy", "area", "edp")
+PENALTY_SHARPNESS = 8.0  # the budget penalty's softplus sharpness
+HV_SAMPLES = 16384  # the hypervolume's quasi-Monte-Carlo sample (3+ objectives)
+
+
+def seed_population(designs: list, n: int, key, sigma: float, dtype) -> tuple[dict, dict]:
+    """``n`` start points, stacked on a leading member axis.  Member ``i``
+    starts from ``designs[i % k]`` (``(tech, arch)`` dicts, the library's
+    numbers); from the second pass over the ``k`` designs on, each parameter
+    moves by log-space jitter ``sigma * N(0, 1)``, clamped into the bounds.
+    The draws come from ``key`` as the population's definition makes them:
+    the key splits into one for tech and one for arch, each into one key per
+    parameter field (fields in their declared order), and each field draws
+    one float32 normal array of shape ``[n, ...]``."""
+    k = len(designs)
+    pick = np.arange(n) % k
+    jittered = np.arange(n) >= k
+    kt, ka = jax.random.split(key)
+    out = []
+    for side, fields, lo, hi, kk in ((0, TECH_FIELDS, TECH_LO, TECH_HI, kt),
+                                     (1, ARCH_FIELDS, ARCH_LO, ARCH_HI, ka)):
+        tree = {}
+        for f, kf in zip(fields, jax.random.split(kk, len(fields))):
+            x = jnp.asarray(np.stack([np.asarray(d[side][f], np.float64) for d in designs])[pick], dtype)
+            noise = sigma * jax.random.normal(kf, x.shape, jnp.float32).astype(dtype)
+            llo = jnp.log(jnp.asarray(np.asarray(lo[f], np.float64), dtype))
+            lhi = jnp.log(jnp.asarray(np.asarray(hi[f], np.float64), dtype))
+            moved = jnp.exp(jnp.clip(jnp.log(x) + noise, llo, lhi))
+            mask = jittered.reshape((n,) + (1,) * (x.ndim - 1))
+            tree[f] = jnp.where(mask, moved, x)
+        out.append(tree)
+    return out[0], out[1]
+
+
+def sample_objective_mixes(n: int, metrics, key, concentration: float) -> np.ndarray:
+    """``[n, 4]`` weights over ``PARETO_METRICS``, one objective mix per
+    member: the first ``len(metrics)`` members take the one-hot corners of
+    the chosen metrics, the rest a float32 Dirichlet(``concentration``) draw
+    from ``key`` over them."""
+    idx = [PARETO_METRICS.index(m) for m in metrics]
+    alpha = jnp.full((len(idx),), jnp.float32(concentration))
+    draws = np.array(jax.random.dirichlet(key, alpha, (n,), jnp.float32), np.float64)
+    k = min(n, len(idx))
+    draws[:k] = np.eye(len(idx))[:k]
+    w = np.zeros((n, len(PARETO_METRICS)))
+    w[:, idx] = draws
+    return w
+
+
+def member_metrics(tz: dict, az: dict, mem_type, graph: dict, dtype):
+    """One member's log metrics (``PARETO_METRICS`` order), area and power,
+    at the log-space parameters ``tz``, ``az`` (one workload)."""
+    tech = {k: jnp.exp(v) for k, v in tz.items()}
+    arch = {k: jnp.exp(v) for k, v in az.items()}
+    r = simulate(tech, arch, mem_type, graph, dtype)
+    logm = jnp.stack([jnp.log(r["runtime_s"]), jnp.log(r["energy_j"]), jnp.log(r["area_mm2"]),
+                      jnp.log(r["edp"])])
+    return logm, r["area_mm2"], r["power_w"]
+
+
+def member_loss(tz, az, mem_type, graph, weights, area_budget, power_budget, penalty_w, dtype):
+    """The mixed objective: the weights' mix of the log metrics, plus
+    ``penalty_w`` times the budget penalty, a softplus hinge on each
+    budget's log violation (``softplus(s v) / s``)."""
+    logm, area, power = member_metrics(tz, az, mem_type, graph, dtype)
+    hinge = lambda v: jax.nn.softplus(PENALTY_SHARPNESS * v) / PENALTY_SHARPNESS
+    penalty = hinge(jnp.log(area) - jnp.log(area_budget)) + hinge(jnp.log(power) - jnp.log(power_budget))
+    return jnp.dot(weights, logm) + penalty_w * penalty, logm
+
+
+def member_descend(tech: dict, arch: dict, mem_type, graph: dict, weights, area_budget, power_budget,
+                   penalty_schedule, lr: float, dtype) -> dict:
+    """One member's descent: per epoch, the mixed objective's value and
+    gradient in log space, one Adam step on tech and arch, the clamp to the
+    bounds, and, where the value or a gradient is not finite, the epoch
+    undone (parameters and Adam state keep their last finite values).
+    ``penalty_schedule`` gives each epoch's penalty weight.  Returns the
+    per-epoch ``[value, log metrics]`` rows (before each epoch's update),
+    the final log-space parameters, the final log metrics, area and power,
+    and the first epoch's gradient."""
+    c = lambda v: jnp.asarray(v, dtype)
+    tz = {k: jnp.log(jnp.maximum(c(v), 1e-30)) for k, v in tech.items()}
+    az = {k: jnp.log(jnp.maximum(c(v), 1e-30)) for k, v in arch.items()}
+    lo_t, hi_t = to_log(cast(TECH_LO, dtype)), to_log(cast(TECH_HI, dtype))
+    lo_a, hi_a = to_log(cast(ARCH_LO, dtype)), to_log(cast(ARCH_HI, dtype))
+    graph = {k: c(v) for k, v in graph.items()}
+    budgets = (c(weights), c(area_budget), c(power_budget))
+    vg = jax.value_and_grad(
+        lambda tz, az, pw: member_loss(tz, az, mem_type, graph, *budgets, pw, dtype),
+        argnums=(0, 1), has_aux=True)
+    zeros = lambda d: {k: jnp.zeros_like(v) for k, v in d.items()}
+
+    def epoch(state, pw):
+        tz, az, mt, vt, ma, va, step = state
+        (val, logm), (gt, ga) = vg(tz, az, pw)
+        ok = jnp.isfinite(val)
+        for g in list(gt.values()) + list(ga.values()):
+            ok = ok & jnp.all(jnp.isfinite(g))
+        nstep = step + 1.0
+        new = []
+        for z, g, m, v, lo, hi in ((tz, gt, mt, vt, lo_t, hi_t), (az, ga, ma, va, lo_a, hi_a)):
+            z2, m2, v2 = {}, {}, {}
+            for k in z:
+                u, m2[k], v2[k] = _adam(g[k], m[k], v[k], nstep, c(lr))
+                z2[k] = jnp.clip(z[k] + u, lo[k], hi[k])
+            new.append((z2, m2, v2))
+        (tz2, mt2, vt2), (az2, ma2, va2) = new
+        cand = (tz2, az2, mt2, vt2, ma2, va2, nstep)
+        state = jax.tree.map(lambda n_, o_: jnp.where(ok, n_, o_), cand, state)
+        return state, jnp.concatenate([val[None], logm])
+
+    _, (gt, ga) = vg(tz, az, c(penalty_schedule[0]))
+    state = (tz, az, zeros(tz), zeros(tz), zeros(az), zeros(az), jnp.float32(0.0))
+    state, rows = jax.lax.scan(epoch, state, c(penalty_schedule))
+    logm, area, power = member_metrics(state[0], state[1], mem_type, graph, dtype)
+    return dict(history=rows, tech=state[0], arch=state[1], log_metrics=logm, area=area, power=power,
+                grad=dict(tech=gt, arch=ga))
+
+
+def non_dominated(points: np.ndarray, feasible: np.ndarray, block: int = 512) -> np.ndarray:
+    """Which points lie on the front, all coordinates costs: a feasible
+    point that no feasible point dominates (``a`` dominates ``b`` when it is
+    nowhere worse and somewhere better).  Infeasible points neither join
+    the front nor shadow a point.  O(n^2), in blocks of dominating rows."""
+    pts = np.asarray(points, np.float64)
+    feas = np.asarray(feasible, bool)
+    cand = pts[feas]
+    dominated = np.zeros(len(pts), bool)
+    for s in range(0, len(cand), block):
+        a = cand[s:s + block, None, :]
+        b = pts[None, :, :]
+        dominated |= np.any(np.all(a <= b, axis=-1) & np.any(a < b, axis=-1), axis=0)
+    return feas & ~dominated
+
+
+def hypervolume(points: np.ndarray, lo: np.ndarray, ref: np.ndarray, tie: float = 0.0) -> tuple:
+    """The volume of the box ``[lo, ref]`` that ``points`` dominate (costs),
+    as the range ``(least, most)`` that comparisons within ``tie`` (relative)
+    of equal allow.  Two objectives: exact, as the area of the staircase.
+    Three or more: the share of a fixed sample of the box that some point
+    dominates, times the box's volume; the sample is ``HV_SAMPLES`` float32
+    uniforms drawn from ``PRNGKey(0)``, mapped onto the box (a float32
+    mapping may put a sample on either side of a point's coordinate)."""
+    pts = np.atleast_2d(np.asarray(points, np.float64))
+    lo, ref = np.asarray(lo, np.float64), np.asarray(ref, np.float64)
+    if pts.shape[1] == 2:
+        p = np.minimum(pts, ref)
+        p = p[np.lexsort((p[:, 1], p[:, 0]))]
+        best = np.minimum.accumulate(p[:, 1])
+        prev = np.concatenate([[ref[1]], best[:-1]])
+        hv = float(np.sum((ref[0] - p[:, 0]) * np.maximum(prev - best, 0.0)))
+        return hv, hv
+    u01 = np.asarray(jax.random.uniform(jax.random.PRNGKey(0), (HV_SAMPLES, pts.shape[1]), jnp.float32),
+                     np.float64)
+    u = np.maximum(lo, u01 * (ref - lo) + lo)
+    slack = tie * np.abs(u)
+    least, most = np.zeros(HV_SAMPLES, bool), np.zeros(HV_SAMPLES, bool)
+    for s in range(0, len(pts), 256):
+        p = pts[s:s + 256, None, :]
+        least |= np.any(np.all(p <= u[None] - slack, axis=-1), axis=0)
+        most |= np.any(np.all(p <= u[None] + slack, axis=-1), axis=0)
+    box = float(np.prod(np.maximum(ref - lo, 0.0)))
+    return box * least.mean(), box * most.mean()

@@ -126,6 +126,34 @@ def span(name: str, on: bool):
         yield
 
 
+class Builds:
+    """The XLA programs this process compiles, and those it loads from the
+    persistent cache instead, from JAX's monitoring events (a load fires no
+    compile event): two counts, and the seconds spent compiling."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax
+
+        self.compiled, self.loaded, self.seconds = 0, 0, 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._timed)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _timed(self, event: str, secs: float, **_) -> None:
+        if event == self.COMPILE:
+            self.compiled += 1
+            self.seconds += secs
+
+    def _event(self, event: str, **_) -> None:
+        if event == self.CACHE_HIT:
+            self.loaded += 1
+
+    def snapshot(self) -> tuple:
+        return self.compiled, self.loaded, self.seconds
+
+
 # --------------------------------------------------------------------------- #
 # set-up
 # --------------------------------------------------------------------------- #
@@ -133,9 +161,10 @@ def span(name: str, on: bool):
 
 class Cell:
     """The cell's inputs as the program takes them: Workloads by graph name
-    (from the configuration's graph files), Architectures by design point."""
+    (from the configuration's graph files), Architectures by design point;
+    and the chips the cell runs on."""
 
-    def __init__(self, config: dict, mix: dict, seed: int, seconds: float):
+    def __init__(self, config: dict, mix: dict, seed: int, seconds: float, chips: int = 1):
         import jax.numpy as jnp
         from repro.api import Workload
         from repro.core.graph import Graph
@@ -143,6 +172,7 @@ class Cell:
         import check as C
 
         self.config, self.mix, self.seed, self.seconds = config, mix, seed, seconds
+        self.chips = chips
         self.workloads = {}
         for name, arrays in C.load_graphs(config).items():
             names = tuple(str(n) for n in arrays["names"])
@@ -311,7 +341,43 @@ class ClosedLoop:
         self.session = None
 
 
-LOOPS = {"open": OpenLoop, "closed": ClosedLoop}
+class FrontierLoop(ClosedLoop):
+    """One client calling ``Session.frontier`` back to back: the population
+    DSE, its members sharded over a ``pop`` mesh of the cell's chips."""
+
+    def __init__(self, cell: Cell, tracing: bool):
+        super().__init__(cell, tracing)
+        self.mesh = pop_mesh(cell.chips)
+
+    def call(self, q: G.Query):
+        """``Session.frontier`` on the query's graph; the population seeds
+        from the library designs the call names, so no architecture."""
+        call = {k: tuple(v) if k in ("seeds", "metrics") else v for k, v in q.call.items()}
+        return self.session.frontier(self.cell.workloads[q.graph], mesh=self.mesh, **call)
+
+    def warm(self) -> None:
+        """Every call the window can send, once: each graph with each key of
+        the mix's pool.  The front's size follows the key, and the program
+        builds the front's and the hypervolume's operations at that size,
+        so only these calls leave the window nothing to build."""
+        base = G.Design(self.cell.mix["architecture"])
+        for kind, g, call in G.calls_of(self.cell.mix, self.cell.config):
+            self.call(G.Query(-1, kind, g, base, call=call))
+
+
+def pop_mesh(chips: int):
+    """A one-axis ``pop`` mesh over the first ``chips`` devices; None for
+    one chip (the population then runs unsharded)."""
+    if chips == 1:
+        return None
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:chips]), ("pop",))
+
+
+LOOPS = {"open": OpenLoop, "closed": ClosedLoop, "frontier": FrontierLoop}
 
 
 # --------------------------------------------------------------------------- #
@@ -379,13 +445,14 @@ def setup(argv, device_check, bench_path: Path):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     sys.path.insert(0, str(ROOT / "src"))
-    cell = Cell(config, mix, args.seed, args.seconds)
+    builds = Builds()
+    cell = Cell(config, mix, args.seed, args.seconds, cell_entry["chips"])
     loop = LOOPS[mix["loop"]](cell, tracing=bool(args.trace))
     t0 = time.perf_counter()
     loop.warm()
     warmup_s = time.perf_counter() - t0
     return dict(args=args, bench=bench, cell_entry=cell_entry, config=config, mix=mix,
-                device=device, cell=cell, loop=loop, warmup_s=warmup_s)
+                device=device, cell=cell, loop=loop, warmup_s=warmup_s, builds=builds)
 
 
 def window(st: dict) -> dict:
@@ -395,6 +462,7 @@ def window(st: dict) -> dict:
 
     args, loop = st["args"], st["loop"]
     traces0 = instrument.trace_count()
+    builds0 = st["builds"].snapshot()
     stats0 = loop.stats()
     tracer = Tracer(bool(args.trace))
     win = loop.run(tracer)
@@ -402,10 +470,12 @@ def window(st: dict) -> dict:
     win["trace_window_s"] = tracer.window_s
     win["traced_done"] = tracer.done
     win["retraces"] = instrument.trace_count() - traces0
+    win["builds"] = [b - a for a, b in zip(builds0, st["builds"].snapshot())]
     stats1 = loop.stats()
     win["stats"] = {k: stats1[k] - stats0[k] for k in stats1}
-    mem = jax.devices()[0].memory_stats() or {}
-    win["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+    # the peak on the fullest of the cell's chips
+    mems = [d.memory_stats() or {} for d in jax.devices()[: st["cell"].chips]]
+    win["memory_peak_bytes"] = max(int(m.get("peak_bytes_in_use", 0)) for m in mems)
     return win
 
 
@@ -449,7 +519,8 @@ def verify(st: dict, win: dict) -> tuple[bool, dict]:
     import check as C
 
     mix = st["mix"]
-    pairs = sample(win["records"], mix["check"]["sample"], st["args"].seed)
+    pairs = [(q, C.host_result(q, r)) for q, r in sample(win["records"], mix["check"]["sample"],
+                                                        st["args"].seed)]
     inputs = free_program(st)
     with on_host():
         readings = C.program_readings(inputs, pairs)
@@ -469,7 +540,12 @@ def main(argv=None, device_check=require_tpu, bench_path: Path = ROOT / "BENCHMA
     log(f"device: platform={st['device']['platform']} device_kind={st['device']['kind']} "
         f"count={st['device']['count']}")
     log(f"generator: attempted={win['attempted']} late_max_s={win['late_max_s']!r}")
+    log(f"set-up: setup_s={setup_s!r} warmup_s={st['warmup_s']!r}; window: "
+        f"{len(win['records'])} calls or queries in {win['t_end'] - win['t_open']!r} s")
     log(f"retraces inside the window: {win['retraces']}")
+    compiled, loaded, secs = win["builds"]
+    log(f"programs compiled inside the window: {compiled} ({secs!r} s); "
+        f"loaded from the persistent cache inside the window: {loaded}")
 
     metrics = {}
     device = dict(st["device"], memory_peak_bytes=win["memory_peak_bytes"])
@@ -493,7 +569,9 @@ def main(argv=None, device_check=require_tpu, bench_path: Path = ROOT / "BENCHMA
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
+    t_check = time.perf_counter()
     correct, checks = verify(st, win)
+    log(f"reference and check: {time.perf_counter() - t_check!r} s")
     failed = sum(1 for _, r, _ in win["records"] if not _ok(r))
     for k, c in checks.items():
         log(f"check {k}: {c['value']!r} limit {c['limit']!r}")

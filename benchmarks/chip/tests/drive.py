@@ -8,7 +8,13 @@ report is built); ``half_batch`` (the second half of each coalesced chunk
 answered with its first lane's result); ``state_unchanged`` (DOpt's Adam
 step returns no update, so the design never moves); ``design_stale`` (an
 optimize reply states its start design, and that design's report, as the
-optimized one)."""
+optimized one); and for frontier replies ``front_dropped`` (the last member
+of the reply's front left out), ``member_frozen`` (the first member of each
+shard of the population keeps its state through every epoch),
+``penalty_skipped`` (the budget penalty left out of the members'
+objective), ``hv_box_shifted`` (the hypervolume taken in a box moved by 0.5
+on each axis) and ``shard_lost`` (the second shard of a sharded population
+answered with the first shard's members)."""
 from __future__ import annotations
 
 import dataclasses
@@ -75,8 +81,90 @@ def plant(fault: str) -> None:
             return dataclasses.replace(res, dhd=start.to_dhd(), optimized=res.baseline)
 
         api.Session.optimize = stale
+    elif fault in FRONTIER_FAULTS:
+        FRONTIER_FAULTS[fault]()
     elif fault != "none":
         raise SystemExit(f"unknown fault {fault!r}")
+
+
+def front_dropped() -> None:
+    from repro import api
+
+    frontier = api.Session.frontier
+
+    def dropped(self, *a, **kw):
+        res = frontier(self, *a, **kw)
+        return dataclasses.replace(res, front=res.front[:-1])
+
+    api.Session.frontier = dropped
+
+
+def member_frozen() -> None:
+    from functools import partial
+
+    import jax
+    from repro import api
+
+    popsim = api._popsim  # the population engine the façade drives
+
+    def frozen(state, mixes, gstack, lr, pw_schedule, spec, mcfg, opt_over):
+        step = partial(popsim._member_step, spec=spec, mcfg=mcfg, opt_over=opt_over)
+
+        def epoch(st, pw):
+            new, m = jax.vmap(lambda t, a, ts, as_, w, ab, pb: step(t, a, ts, as_, w, ab, pb, gstack, lr, pw)
+                              )(*st, *mixes)
+            return jax.tree.map(lambda n, o: n.at[0].set(o[0]), new, st), m
+
+        return jax.lax.scan(epoch, state, pw_schedule)
+
+    popsim._population_scan = frozen
+
+
+def penalty_skipped() -> None:
+    from repro import api
+
+    popsim = api._popsim
+    mixed = popsim.mixed_log_objective
+
+    def unpenalized(tech, arch, gs, weights, area_budget, power_budget, penalty_w, spec, mcfg):
+        return mixed(tech, arch, gs, weights, area_budget, power_budget, 0.0 * penalty_w, spec, mcfg)
+
+    popsim.mixed_log_objective = unpenalized
+
+
+def hv_box_shifted() -> None:
+    import numpy as np
+    from repro import api
+
+    popsim = api._popsim
+    dse = popsim.pareto_dse
+
+    def shifted(*a, hv_box=None, **kw):
+        return dse(*a, hv_box=tuple(np.asarray(b, np.float32) + 0.5 for b in hv_box), **kw)
+
+    popsim.pareto_dse = shifted
+
+
+def shard_lost() -> None:
+    import jax
+    from repro import api
+
+    popsim = api._popsim
+    chunk = popsim.population_chunk
+
+    def lost(state, *a, mesh=None, **kw):
+        state, m = chunk(state, *a, mesh=mesh, **kw)
+        if mesh is None or mesh.size == 1:
+            return state, m
+        q = m.shape[1] // mesh.size
+        return (jax.tree.map(lambda x: x.at[q:2 * q].set(x[:q]), state),
+                m.at[:, q:2 * q].set(m[:, :q]))
+
+    popsim.population_chunk = lost
+
+
+FRONTIER_FAULTS = {f.__name__: f for f in (front_dropped, member_frozen, penalty_skipped,
+                                           hv_box_shifted, shard_lost)}
 
 
 if __name__ == "__main__":

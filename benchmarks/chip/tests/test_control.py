@@ -18,10 +18,7 @@ DOPT = DOPT_MIX["check"]
 
 
 @pytest.fixture(scope="module")
-def inputs():
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
+def inputs(x64):
     return C.Inputs(dict(CONFIG, dir=str(CHIP)))
 
 
@@ -51,3 +48,18 @@ def test_control_fails_elasticity_limit(inputs, graph):
     q = G.Query(0, "explain", graph, G.Design("edge"), call={"objective": "edp"})
     readings = C.control_readings(inputs, [q])
     assert readings["elasticity_gap"] > SWEEP["elasticity_gap"], readings
+
+
+FRONTIER_MIX = json.loads((CHIP / "traffic" / "frontier.json").read_text())
+LM = json.loads((CHIP / "configs" / "lm_1024.json").read_text())
+
+
+@pytest.mark.parametrize("graph", [g["name"] for g in LM["graphs"]])
+def test_control_fails_frontier_limits(x64, graph):
+    """The frontier cell's own call, at its population, on each of its
+    graphs: the members the check follows, descended in bfloat16."""
+    inputs = C.Inputs(dict(LM, dir=str(CHIP)))
+    call = G.call_for(FRONTIER_MIX, "frontier", graph, FRONTIER_MIX["keys"][0])
+    q = G.Query(0, "frontier", graph, G.Design("base"), call=call)
+    ok, checks = C.judge(C.control_readings(inputs, [q]), FRONTIER_MIX["check"]["limits"])
+    assert not ok, checks

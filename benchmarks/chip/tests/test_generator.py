@@ -13,8 +13,8 @@ CHIP = G.__file__.rsplit("/", 1)[0]
 LM = json.load(open(f"{CHIP}/configs/lm_1024.json"))
 ML = json.load(open(f"{CHIP}/configs/mlperf_small.json"))
 MIXES = {n: json.load(open(f"{CHIP}/traffic/{n}.json"))
-         for n in ("design_open", "notebook_sweep", "optimize")}
-CONFIG = {"design_open": LM, "notebook_sweep": ML, "optimize": LM}
+         for n in ("design_open", "notebook_sweep", "optimize", "frontier")}
+CONFIG = {"design_open": LM, "notebook_sweep": ML, "optimize": LM, "frontier": LM}
 SEEDS = (7, 2**31 + 5)
 
 
@@ -26,7 +26,7 @@ def schedule(name, seed, seconds=20.0, n=400):
 
 
 def key(q):
-    return (q.kind, q.graph, q.design, q.due_s, tuple(sorted(q.call.items())))
+    return (q.kind, q.graph, q.design, q.due_s, json.dumps(q.call, sort_keys=True), q.draw)
 
 
 @pytest.mark.parametrize("name", sorted(MIXES))
@@ -81,3 +81,51 @@ def test_bursts_are_due_together():
 def test_closed_loop_same_work_per_block(name):
     per = [Counter((q.kind, q.graph) for q in schedule(name, s, n=G.BLOCK * 6)) for s in SEEDS]
     assert per[0] == per[1]
+
+
+def test_frontier_stream_same_work_every_seed():
+    """Frontier calls alternate the graphs, from the mix's first graph,
+    with each graph's budgets and box; each graph takes every key of the
+    mix's pool once a round, so every seed sends the same calls (a window
+    holds only a few), warm-up runs exactly the calls the window can send,
+    and the seed draws which members the check follows."""
+    mix = MIXES["frontier"]
+    pool = mix["keys"]
+    rounds = 5
+    streams = [schedule("frontier", s, n=2 * len(pool) * rounds) for s in SEEDS]
+    for qs in streams:
+        graphs = [q.graph for q in qs]
+        assert graphs[0] == mix["first_graph"]
+        assert graphs[::2] == [graphs[0]] * (len(qs) // 2) and graphs[1::2] == [graphs[1]] * (len(qs) // 2)
+        assert graphs[0] != graphs[1]
+        for q in qs:
+            assert q.kind == "frontier"
+            want = dict(G.call_of(mix, "frontier"), **mix["per_graph"][q.graph])
+            assert {k: v for k, v in q.call.items() if k != "key"} == want
+            assert 0 <= q.call["key"] < 2**31
+        for g in set(graphs):
+            keys = [q.call["key"] for q in qs if q.graph == g]
+            for r in range(rounds):
+                assert sorted(keys[r * len(pool):(r + 1) * len(pool)]) == sorted(pool)
+        warm = {(k, g, json.dumps(c, sort_keys=True)) for k, g, c in G.calls_of(mix, LM)}
+        assert {(q.kind, q.graph, json.dumps(q.call, sort_keys=True)) for q in qs} == warm
+    calls = [[(q.graph, json.dumps(q.call, sort_keys=True)) for q in qs] for qs in streams]
+    assert calls[0] == calls[1]
+    assert streams[0][0].draw != streams[1][0].draw
+
+
+def test_frontier_budgets_are_the_seed_designs(x64):
+    """Each graph's budgets and box are what ``seed_budgets.py`` derives
+    from the seed designs with the plain reference."""
+    import seed_budgets as S
+
+    mix = MIXES["frontier"]
+    assert mix["per_graph"] == S.per_graph(dict(LM, dir=CHIP), mix["call"]["frontier"])
+
+
+def test_existing_streams_draw_no_key():
+    """Mixes without ``draw_key`` and ``per_graph`` send the mix's call
+    arguments and nothing else."""
+    for name in ("notebook_sweep", "optimize"):
+        for q in schedule(name, SEEDS[0], n=20):
+            assert q.call == G.call_of(MIXES[name], q.kind)
