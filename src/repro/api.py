@@ -205,6 +205,7 @@ class Workload:
             raise ValueError(f"{len(self.labels)} labels for {len(self.graphs)} graphs")
         vmax = max(g.n_vertices for g in self.graphs)
         self._bucket = (len(self.graphs), _bucket_vertices(vmax))
+        self._vertices = sum(g.n_vertices for g in self.graphs)
         self._stacked: Graph | None = None
 
     @staticmethod
@@ -233,6 +234,11 @@ class Workload:
     @property
     def n_workloads(self) -> int:
         return len(self.graphs)
+
+    @property
+    def vertices(self) -> int:
+        """Real vertices over the set's graphs, before bucket padding."""
+        return self._vertices
 
     @property
     def stacked(self) -> Graph:
@@ -918,10 +924,11 @@ class Session:
         )
 
     def _batch_span_args(self, workloads) -> dict:
-        """``n`` and ``bucket`` of a batched call, for its span."""
+        """``n``, ``bucket`` and real ``vertices`` of a batched call, for its span."""
         if not workloads:
             return dict(n=0)
-        return dict(n=len(workloads), bucket=self._workload(workloads[0]).bucket)
+        ws = [self._workload(w) for w in workloads]
+        return dict(n=len(ws), bucket=ws[0].bucket, vertices=sum(w.vertices for w in ws))
 
     def _assemble_batch(self, workloads, architectures, request_bucket=None):
         """Validate + stack a request batch: every item must share the
@@ -1057,7 +1064,7 @@ class Session:
         """Simulate the workload set; returns a :class:`SimReport` with
         per-workload totals and per-memory-level / per-vertex breakdowns."""
         w, a = self._workload(workload), self._arch(architecture)
-        with instrument.span("dragon.session.simulate", bucket=w.bucket, n=1):
+        with instrument.span("dragon.session.simulate", bucket=w.bucket, vertices=w.vertices, n=1):
             prog = self._report_program(w.bucket, a.spec, self.mcfg)
             with instrument.span("dragon.session.launch", program="report"):
                 out = prog(a.tech, a.arch, w.stacked)
@@ -1072,7 +1079,7 @@ class Session:
         d log(objective) / d log(parameter) — DOpt's Table-3 signal, served
         as an explanation instead of a descent direction."""
         w, a = self._workload(workload), self._arch(architecture)
-        with instrument.span("dragon.session.explain", bucket=w.bucket, n=1):
+        with instrument.span("dragon.session.explain", bucket=w.bucket, vertices=w.vertices, n=1):
             rep = self.simulate(w, architecture=a)
             prog = self._explain_program(w.bucket, a.spec, self.mcfg, objective)
             with instrument.span("dragon.session.launch", program="explain"):
@@ -1108,7 +1115,7 @@ class Session:
         mode where only the descent itself should be on the clock.
         """
         w, a = self._workload(workload), self._arch(architecture)
-        with instrument.span("dragon.session.optimize", bucket=w.bucket, n=1):
+        with instrument.span("dragon.session.optimize", bucket=w.bucket, vertices=w.vertices, n=1):
             mcfg = engine_kw.pop("mcfg", self.mcfg)
             # everything static to the engine's fused-chunk program belongs in
             # the key: steps/target_factor/chunk set the scan length, and
@@ -1179,7 +1186,7 @@ class Session:
         ``sigma``, ``mesh``, ``key``, ``hv_box``, ...).
         """
         w = self._workload(workload)
-        with instrument.span("dragon.session.frontier", bucket=w.bucket, n=1):
+        with instrument.span("dragon.session.frontier", bucket=w.bucket, vertices=w.vertices, n=1):
             mcfg = engine_kw.pop("mcfg", self.mcfg)
             self._engine_call(
                 ("frontier", mcfg, w.bucket, tuple(metrics), tuple(seeds),

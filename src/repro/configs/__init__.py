@@ -1,5 +1,6 @@
 """Architecture config registry — import side-effect registers all archs."""
 from repro.configs.base import (  # noqa: F401
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     SSMConfig,
@@ -10,6 +11,7 @@ from repro.configs.base import (  # noqa: F401
     cell_status,
     get_config,
     register,
+    traced_archs,
 )
 
 # one module per assigned architecture (+ the paper's own workload configs live
@@ -18,6 +20,7 @@ from repro.configs import (  # noqa: F401
     falcon_mamba_7b,
     granite_3_8b,
     kimi_k2_1t_a32b,
+    kimi_k2_instruct,
     llama4_scout_17b_a16e,
     llama_3_2_vision_11b,
     minitron_8b,

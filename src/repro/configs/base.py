@@ -23,6 +23,24 @@ class MoEConfig:
     router_dtype: str = "float32"
     # capacity factor used for sizing dense one-hot dispatch (GSPMD-friendly)
     capacity_factor: float = 1.25
+    n_shared: int = 0  # shared experts beside the routed ones, d_ff_expert wide each
+    first_k_dense: int = 0  # leading layers whose MLP is dense, ModelConfig.d_ff wide
+    routed_scaling: float = 1.0  # factor on the routed experts' combined output
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2 §2.1).  Queries pass through a
+    ``q_lora_rank`` latent, keys and values through one ``kv_lora_rank``
+    latent shared by every head (the decode cache).  Each head's query and
+    key split into ``qk_nope_head_dim`` features without position and
+    ``qk_rope_head_dim`` rotary ones, whose key is one head shared by all."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
 
 
 @dataclass(frozen=True)
@@ -80,6 +98,7 @@ class ModelConfig:
     vision: Optional[VisionConfig] = None
     audio: Optional[AudioConfig] = None
     hybrid: Optional[HybridConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
     # runtime knobs (overridable per launch)
     dtype: str = "bfloat16"
     param_dtype: str = "float32"  # weight storage; "bfloat16" for 1T-scale
@@ -143,6 +162,16 @@ class ModelConfig:
         )
         if self.qkv_bias:
             attn_layer += self.n_heads * hd + 2 * self.n_kv_heads * hd
+        if self.mla:
+            m, nh = self.mla, self.n_heads
+            attn_layer = (
+                d * m.q_lora_rank + m.q_lora_rank  # q down-projection, its norm
+                + m.q_lora_rank * nh * (m.qk_nope_head_dim + m.qk_rope_head_dim)  # q up
+                + d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank  # kv down, norm
+                + m.kv_lora_rank * nh * (m.qk_nope_head_dim + m.v_head_dim)  # kv up (K, V)
+                + nh * m.v_head_dim * d  # Wo
+                + (2 * d)  # norms (pre-attn + pre-mlp)
+            )
         if self.family in ("dense", "vlm", "audio", "moe"):
             n_attn = self.n_layers
         mlp = {
@@ -161,8 +190,9 @@ class ModelConfig:
         elif self.family == "moe":
             e = self.moe
             expert = 3 * d * e.d_ff_expert  # swiglu experts
-            total = self.n_layers * (
-                attn_layer + e.n_experts * expert + d * e.n_experts  # router
+            total = self.n_layers * attn_layer + e.first_k_dense * mlp
+            total += (self.n_layers - e.first_k_dense) * (
+                (e.n_experts + e.n_shared) * expert + d * e.n_experts  # router
             )
         elif self.family == "ssm":
             di, s = self.d_inner, self.ssm
@@ -213,7 +243,7 @@ class ModelConfig:
         e = self.moe
         d = self.d_model
         expert = 3 * d * e.d_ff_expert
-        inactive = self.n_layers * (e.n_experts - e.top_k) * expert
+        inactive = (self.n_layers - e.first_k_dense) * (e.n_experts - e.top_k) * expert
         return self.param_count() - inactive
 
     # -------------------------------------------------------------- reduced
@@ -289,6 +319,18 @@ def get_config(name: str) -> ModelConfig:
 
 
 def all_archs() -> list[str]:
+    """The registered architectures that ``repro.models`` builds (the
+    assigned grid): all but those with latent attention, shared experts or
+    leading dense layers, which only the tracer prices and
+    :func:`traced_archs` adds."""
+    import repro.configs  # noqa: F401
+
+    return sorted(a for a, c in _REGISTRY.items()
+                  if c.mla is None and not (c.moe and (c.moe.n_shared or c.moe.first_k_dense)))
+
+
+def traced_archs() -> list[str]:
+    """Every registered architecture: ``core.trace.trace_lm`` traces each."""
     import repro.configs  # noqa: F401
 
     return sorted(_REGISTRY)

@@ -128,6 +128,9 @@ class GraphBuilder:
         self._edges: list[tuple[int, int]] = []
         self._last: int | None = None
 
+    def __len__(self) -> int:
+        return len(self._rows)
+
     def add(
         self,
         name: str,

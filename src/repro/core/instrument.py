@@ -76,9 +76,22 @@ def reset(prefix: str | None = None) -> None:
 # --------------------------------------------------------------------------- #
 
 _Annotation = jax.profiler.TraceAnnotation
-_INERT = contextlib.nullcontext()
 _tls = threading.local()  # .chunk: the innermost open span's chunk id
 _gc_lock = threading.Lock()
+
+
+class _Inert(contextlib.nullcontext):
+    """The span handed out with no profiler session: enters as itself and
+    records nothing."""
+
+    def __init__(self):
+        super().__init__(self)
+
+    def set(self, **args) -> None:
+        pass
+
+
+_INERT = _Inert()
 
 
 class _Span:
@@ -103,14 +116,19 @@ class _Span:
         _tls.chunk = self._outer
         return False
 
+    def set(self, **args) -> None:
+        """Add args known only once the spanned work is done."""
+        self._ann.set_metadata(**args)
+
 
 def span(name: str, lazy=None, /, **args):
     """A context manager that records ``name`` as a profiler span.
 
     ``args`` become the event's stats; ``lazy``, when given, is a callable
     returning a dict of further args, called only when a profiler session is
-    active.  With no session the call returns a shared inert context and
-    computes nothing."""
+    active; the entered span's ``set(**args)`` adds args at its end.  With
+    no session the call returns a shared inert context and computes
+    nothing."""
     if not _Annotation.is_enabled():
         return _INERT
     if lazy is not None:

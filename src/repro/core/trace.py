@@ -2,9 +2,11 @@
 
 Emits an operator-level DFG with exact FLOP / byte counts for every assigned
 architecture family (dense GQA transformer, MoE, Mamba1 SSM, Mamba2 hybrid,
-VLM cross-attention, audio-token decoder).  These graphs feed DSim/DOpt (the
-paper's 'modern AI workloads') and are cross-checked against the compiled
-HLO FLOPs of the real JAX models in tests.
+VLM cross-attention, audio-token decoder), and for latent-attention MoE
+models with shared experts and leading dense layers (Kimi-K2).  These graphs
+feed DSim/DOpt (the paper's 'modern AI workloads') and are cross-checked
+against the compiled HLO FLOPs of the real JAX models, and against the
+``dot_general`` FLOPs of ``workloads/kimi_reference.py``, in tests.
 
 Conventions:
   * bf16 operands: 2 bytes/element.
@@ -12,12 +14,36 @@ Conventions:
   * decode mode: S_q = 1 against a KV cache of length S (read from mainMem).
   * weights stream from mainMem each use (the mapper's prefetch/tiling decides
     what is actually resident — see mapper.py).
+
+Latent attention (``cfg.mla``; T tokens, h heads, ranks r_q and r, head
+dims n (no position), p (rotary), v).  Every phase first projects:
+q_a [T,d]x[d,r_q], its RMSNorm, q_b [T,r_q]x[r_q,h(n+p)], kv_a
+[T,d]x[d,r+p], its RMSNorm over r, and RoPE over the p-wide query and key
+parts.  Then, by phase:
+
+  * prefill and train decompress: kv_b [T,r]x[r,h(n+v)] rebuilds every
+    head's key and value, and attention is plain multi-head with query/key
+    head n+p and value head v (causal: half the S x S scores);
+  * decode absorbs kv_b into the query and the output: q_absorb, per head
+    [1,n]x[n,r], maps each query into the latent; the scores, 2 B h S_kv
+    (r+p) FLOPs, and the AV, 2 B h S_kv r, run over one latent "head"
+    shared by all h; v_up, per head [1,r]x[r,v], leaves the latent.  The
+    cache is the latent and the rotary key, B S_kv (r+p) x 2 bytes, read
+    from mainMem once by the scores; the AV reuses its r-wide part.
+
+Decode absorbs because one token's query then meets the cache as it is
+stored (no h-fold decompression of S_kv keys and values a step); prefill
+and train decompress because there S tokens share one decompression and
+the attention runs at head width n+p rather than r+p.
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import MLAConfig, ModelConfig, ShapeConfig
+from repro.core import instrument
 from repro.core.graph import (
     CONV,
     ELEMWISE,
@@ -34,13 +60,16 @@ from repro.core.graph import (
 BYTES = 2.0  # bf16
 
 
-def _mm(b: GraphBuilder, name: str, M: float, K: float, N: float, *, mode: str, w_resident: bool = False):
-    """A weight matmul [M,K]x[K,N]: activations in globalBuf, weights from mainMem."""
+def _mm(b: GraphBuilder, name: str, M: float, K: float, N: float, *, mode: str, w_resident: bool = False,
+        groups: float = 1.0):
+    """A weight matmul [M,K]x[K,N]: activations in globalBuf, weights from mainMem.
+    ``groups`` such matmuls side by side, each with its own weight (a
+    per-head projection), make one vertex."""
     mult = 3.0 if mode == "train" else 1.0
-    flops = 2.0 * M * K * N * mult
-    w_bytes = K * N * BYTES
-    act_in = M * K * BYTES
-    act_out = M * N * BYTES
+    flops = 2.0 * groups * M * K * N * mult
+    w_bytes = groups * K * N * BYTES
+    act_in = groups * M * K * BYTES
+    act_out = groups * M * N * BYTES
     b.add(
         name,
         MATMUL,
@@ -50,7 +79,7 @@ def _mm(b: GraphBuilder, name: str, M: float, K: float, N: float, *, mode: str, 
         main_read=0.0 if w_resident else w_bytes * (2.0 if mode == "train" else 1.0),
         main_write=w_bytes if mode == "train" else 0.0,  # weight grads
         alloc=act_in + act_out + w_bytes,
-        dims=(M, N, K),
+        dims=(groups * M, N, K),
     )
 
 
@@ -99,8 +128,111 @@ def _attention(b: GraphBuilder, name: str, Bq: float, Sq: float, Skv: float, nh:
     )
 
 
+def _mla(b: GraphBuilder, p: str, m: MLAConfig, B: float, S: float, Skv: float, d: float, nh: int, *, mode: str):
+    """Latent attention after the pre-attention norm, ``o`` included: the
+    projections, then the decompressed form (prefill, train) or the absorbed
+    one (decode) of the module docstring."""
+    T = B * S
+    r, n, pe, v = float(m.kv_lora_rank), float(m.qk_nope_head_dim), float(m.qk_rope_head_dim), float(m.v_head_dim)
+    mult = 3.0 if mode == "train" else 1.0
+    _mm(b, f"{p}.q_a", T, d, m.q_lora_rank, mode=mode)
+    _ew(b, f"{p}.q_norm", T * m.q_lora_rank, 8.0, mode=mode, kind=REDUCTION)
+    _mm(b, f"{p}.q_b", T, m.q_lora_rank, nh * (n + pe), mode=mode)
+    _mm(b, f"{p}.kv_a", T, d, r + pe, mode=mode)
+    _ew(b, f"{p}.kv_norm", T * r, 8.0, mode=mode, kind=REDUCTION)
+    _ew(b, f"{p}.rope", T * (nh + 1) * pe, 6.0, mode=mode)
+    if mode == "decode":
+        _mm(b, f"{p}.q_absorb", T, n, r, mode=mode, groups=nh)
+        q_bytes = B * nh * S * (r + pe) * BYTES  # absorbed queries
+        cache = B * Skv * (r + pe) * BYTES  # the latent and the rotary key
+        s_bytes = B * nh * S * Skv * BYTES
+        o_bytes = B * nh * S * r * BYTES
+        b.add(f"{p}.mla.scores", MATMUL, 2.0 * B * nh * S * Skv * (r + pe),
+              gbuf_read=q_bytes + cache, gbuf_write=s_bytes, main_read=cache,
+              alloc=q_bytes + cache + s_bytes, dims=(B * nh * S, Skv, r + pe))
+        _ew(b, f"{p}.mla.softmax", B * nh * S * Skv, 5.0, mode=mode, kind=SOFTMAX)
+        latent = B * Skv * r * BYTES  # fetched by the scores
+        b.add(f"{p}.mla.av", MATMUL, 2.0 * B * nh * S * Skv * r,
+              gbuf_read=s_bytes + latent, gbuf_write=o_bytes,
+              alloc=s_bytes + latent + o_bytes, dims=(B * nh * S, r, Skv))
+        _mm(b, f"{p}.v_up", T, r, v, mode=mode, groups=nh)
+    else:
+        _mm(b, f"{p}.kv_b", T, r, nh * (n + v), mode=mode)
+        frac = 0.5 if S == Skv else 1.0  # causal
+        q_bytes = B * nh * S * (n + pe) * BYTES
+        k_bytes = B * nh * Skv * (n + pe) * BYTES
+        v_bytes = B * nh * Skv * v * BYTES
+        o_bytes = B * nh * S * v * BYTES
+        s_bytes = B * nh * S * Skv * frac * BYTES
+        b.add(f"{p}.mla.scores", MATMUL, 2.0 * B * nh * S * Skv * (n + pe) * frac * mult,
+              gbuf_read=(q_bytes + k_bytes) * mult, gbuf_write=s_bytes * mult,
+              alloc=q_bytes + k_bytes + s_bytes, dims=(B * nh * S, Skv * frac, n + pe))
+        _ew(b, f"{p}.mla.softmax", B * nh * S * Skv * frac, 5.0, mode=mode, kind=SOFTMAX)
+        b.add(f"{p}.mla.av", MATMUL, 2.0 * B * nh * S * Skv * v * frac * mult,
+              gbuf_read=(s_bytes + v_bytes) * mult, gbuf_write=o_bytes * mult,
+              alloc=s_bytes + v_bytes + o_bytes, dims=(B * nh * S, v, Skv * frac))
+    _mm(b, f"{p}.o", T, nh * v, d, mode=mode)
+
+
+def _routed(b: GraphBuilder, p: str, e, T: float, d: float, *, mode: str):
+    """The routed experts after the pre-MLP norm: router, top-k, dispatch,
+    the experts (top_k active per token, routing priced uniform), combine."""
+    _ew(b, f"{p}.norm2", T * d, 8.0, mode=mode, kind=REDUCTION)
+    _mm(b, f"{p}.router", T, d, e.n_experts, mode=mode)
+    _ew(b, f"{p}.topk", T * e.n_experts, 3.0, mode=mode, kind=REDUCTION)
+    # dispatch + expert FFN (top_k experts active per token) + combine
+    mult = 3.0 if mode == "train" else 1.0
+    tok = T * e.top_k
+    w_bytes = e.n_experts * 3 * d * e.d_ff_expert * BYTES
+    # weights of ALL routed-to experts stream from main memory — the
+    # hallmark mainMem pressure of MoE (capped by total expert bytes)
+    act_expert_w = min(w_bytes, tok * 3 * d * e.d_ff_expert * BYTES)
+    b.add(
+        f"{p}.dispatch",
+        GATHER,
+        tok * d,
+        gbuf_read=T * d * BYTES * mult,
+        gbuf_write=tok * d * BYTES * mult,
+        alloc=(T + tok) * d * BYTES,
+        dims=(tok, d, 1.0),
+    )
+    b.add(
+        f"{p}.experts",
+        MATMUL,
+        2.0 * tok * 3 * d * e.d_ff_expert * mult,
+        gbuf_read=(tok * d * BYTES + act_expert_w) * mult,
+        gbuf_write=tok * d * BYTES * mult,
+        main_read=act_expert_w * (2.0 if mode == "train" else 1.0),
+        main_write=w_bytes if mode == "train" else 0.0,
+        alloc=tok * d * BYTES * 2 + act_expert_w,
+        dims=(tok, e.d_ff_expert, d),
+    )
+    b.add(
+        f"{p}.combine",
+        GATHER,
+        tok * d * 2,
+        gbuf_read=tok * d * BYTES * mult,
+        gbuf_write=T * d * BYTES * mult,
+        alloc=(T + tok) * d * BYTES,
+        dims=(T, d, 1.0),
+    )
+
+
+# roles of the vertices ``dragon.trace.lm`` counts: attention layers by
+# form, and the three kinds of MLP
+ROLES = ("mla", "gqa", "routed", "shared", "dense")
+
+
 def trace_lm(cfg: ModelConfig, shape: ShapeConfig) -> Graph:
     """Build the operator DFG for one (architecture x shape) cell."""
+    with instrument.span("dragon.trace.lm", arch=cfg.name, shape=shape.name) as sp:
+        g, roles = _trace(cfg, shape)
+        sp.set(vertices=g.n_vertices, **roles)
+    return g
+
+
+def _trace(cfg: ModelConfig, shape: ShapeConfig) -> tuple[Graph, dict]:
+    """The DFG, and its count of vertices by role (``ROLES``)."""
     mode = shape.kind  # train | prefill | decode
     B = float(shape.global_batch)
     S = 1.0 if mode == "decode" else float(shape.seq_len)
@@ -143,63 +275,52 @@ def trace_lm(cfg: ModelConfig, shape: ShapeConfig) -> Graph:
         _ew(b, f"{prefix}{i}.act", T * width, 4.0, mode=mode)
         _mm(b, f"{prefix}{i}.mlp_down", T, width, d, mode=mode)
 
+    roles = dict.fromkeys(ROLES, 0)
+
+    @contextlib.contextmanager
+    def counted(role: str):
+        n0 = len(b)
+        yield
+        roles[role] += len(b) - n0
+
     if cfg.family in ("dense", "audio", "vlm"):
         for i in range(cfg.n_layers):
             is_cross = cfg.vision and (i + 1) % cfg.vision.cross_attn_every == 0
-            if is_cross:
-                P = float(cfg.vision.n_patches)
-                _ew(b, f"L{i}.norm1", T * d, 8.0, mode=mode, kind=REDUCTION)
-                _mm(b, f"L{i}.q", T, d, nh * hd, mode=mode)
-                _mm(b, f"L{i}.kv_img", B * P, d, 2 * kv * hd, mode=mode)
-                _attention(b, f"L{i}.xattn", B, S, P, nh, kv, hd, mode=mode, causal=False)
-                _mm(b, f"L{i}.o", T, nh * hd, d, mode=mode)
-            else:
-                dense_attn_layer(i, "L", Skv)
-            mlp(i, "L", ff)
+            with counted("gqa"):
+                if is_cross:
+                    P = float(cfg.vision.n_patches)
+                    _ew(b, f"L{i}.norm1", T * d, 8.0, mode=mode, kind=REDUCTION)
+                    _mm(b, f"L{i}.q", T, d, nh * hd, mode=mode)
+                    _mm(b, f"L{i}.kv_img", B * P, d, 2 * kv * hd, mode=mode)
+                    _attention(b, f"L{i}.xattn", B, S, P, nh, kv, hd, mode=mode, causal=False)
+                    _mm(b, f"L{i}.o", T, nh * hd, d, mode=mode)
+                else:
+                    dense_attn_layer(i, "L", Skv)
+            with counted("dense"):
+                mlp(i, "L", ff)
 
     elif cfg.family == "moe":
         e = cfg.moe
         for i in range(cfg.n_layers):
-            dense_attn_layer(i, "L", Skv)
-            _ew(b, f"L{i}.norm2", T * d, 8.0, mode=mode, kind=REDUCTION)
-            _mm(b, f"L{i}.router", T, d, e.n_experts, mode=mode)
-            _ew(b, f"L{i}.topk", T * e.n_experts, 3.0, mode=mode, kind=REDUCTION)
-            # dispatch + expert FFN (top_k experts active per token) + combine
-            mult = 3.0 if mode == "train" else 1.0
-            tok = T * e.top_k
-            w_bytes = e.n_experts * 3 * d * e.d_ff_expert * BYTES
-            # weights of ALL routed-to experts stream from main memory — the
-            # hallmark mainMem pressure of MoE (capped by total expert bytes)
-            act_expert_w = min(w_bytes, tok * 3 * d * e.d_ff_expert * BYTES)
-            b.add(
-                f"L{i}.dispatch",
-                GATHER,
-                tok * d,
-                gbuf_read=T * d * BYTES * mult,
-                gbuf_write=tok * d * BYTES * mult,
-                alloc=(T + tok) * d * BYTES,
-                dims=(tok, d, 1.0),
-            )
-            b.add(
-                f"L{i}.experts",
-                MATMUL,
-                2.0 * tok * 3 * d * e.d_ff_expert * mult,
-                gbuf_read=(tok * d * BYTES + act_expert_w) * mult,
-                gbuf_write=tok * d * BYTES * mult,
-                main_read=act_expert_w * (2.0 if mode == "train" else 1.0),
-                main_write=w_bytes if mode == "train" else 0.0,
-                alloc=tok * d * BYTES * 2 + act_expert_w,
-                dims=(tok, e.d_ff_expert, d),
-            )
-            b.add(
-                f"L{i}.combine",
-                GATHER,
-                tok * d * 2,
-                gbuf_read=tok * d * BYTES * mult,
-                gbuf_write=T * d * BYTES * mult,
-                alloc=(T + tok) * d * BYTES,
-                dims=(T, d, 1.0),
-            )
+            if cfg.mla:
+                with counted("mla"):
+                    _ew(b, f"L{i}.norm1", T * d, 8.0, mode=mode, kind=REDUCTION)
+                    _mla(b, f"L{i}", cfg.mla, B, S, Skv, d, nh, mode=mode)
+            else:
+                with counted("gqa"):
+                    dense_attn_layer(i, "L", Skv)
+            if i < e.first_k_dense:
+                with counted("dense"):
+                    mlp(i, "L", ff)
+                continue
+            with counted("routed"):
+                _routed(b, f"L{i}", e, T, d, mode=mode)
+            if e.n_shared:
+                with counted("shared"):
+                    w = float(e.n_shared * e.d_ff_expert)
+                    _mm(b, f"L{i}.shared_up", T, d, 2 * w, mode=mode)
+                    _ew(b, f"L{i}.shared_act", T * w, 4.0, mode=mode)
+                    _mm(b, f"L{i}.shared_down", T, w, d, mode=mode)
 
     elif cfg.family == "ssm":
         s, di, dtr = cfg.ssm, float(cfg.d_inner), float(cfg.dt_rank)
@@ -244,13 +365,15 @@ def trace_lm(cfg: ModelConfig, shape: ShapeConfig) -> Graph:
             _mm(b, f"L{i}.out_proj", T, di, d, mode=mode)
             if (i + 1) % h.attn_every == 0:
                 # shared attention block on concat(hidden, embed): 2d -> heads
-                _ew(b, f"L{i}.snorm", T * 2 * d, 8.0, mode=mode, kind=REDUCTION)
-                _mm(b, f"L{i}.sqkv", T, 2 * d, (nh + 2 * kv) * hd, mode=mode, w_resident=True)
-                kv_main = B * kv * Skv * hd * 2 * BYTES if mode == "decode" else 0.0
-                _attention(b, f"L{i}.sattn", B, S, Skv, nh, kv, hd, mode=mode, causal=True, kv_from_main=kv_main)
-                _mm(b, f"L{i}.so", T, nh * hd, d, mode=mode, w_resident=True)
-                _mm(b, f"L{i}.smlp_up", T, d, 3 * h.shared_attn_mlp_ff - h.shared_attn_mlp_ff, mode=mode, w_resident=True)
-                _mm(b, f"L{i}.smlp_down", T, h.shared_attn_mlp_ff, d, mode=mode, w_resident=True)
+                with counted("gqa"):
+                    _ew(b, f"L{i}.snorm", T * 2 * d, 8.0, mode=mode, kind=REDUCTION)
+                    _mm(b, f"L{i}.sqkv", T, 2 * d, (nh + 2 * kv) * hd, mode=mode, w_resident=True)
+                    kv_main = B * kv * Skv * hd * 2 * BYTES if mode == "decode" else 0.0
+                    _attention(b, f"L{i}.sattn", B, S, Skv, nh, kv, hd, mode=mode, causal=True, kv_from_main=kv_main)
+                    _mm(b, f"L{i}.so", T, nh * hd, d, mode=mode, w_resident=True)
+                with counted("dense"):
+                    _mm(b, f"L{i}.smlp_up", T, d, 3 * h.shared_attn_mlp_ff - h.shared_attn_mlp_ff, mode=mode, w_resident=True)
+                    _mm(b, f"L{i}.smlp_down", T, h.shared_attn_mlp_ff, d, mode=mode, w_resident=True)
     else:
         raise ValueError(cfg.family)
 
@@ -260,7 +383,7 @@ def trace_lm(cfg: ModelConfig, shape: ShapeConfig) -> Graph:
     if mode == "train":
         _ew(b, "xent", T * V, 6.0, mode=mode, kind=SOFTMAX)
 
-    return b.build()
+    return b.build(), roles
 
 
 # --------------------------------------------------------------------------- #

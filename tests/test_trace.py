@@ -8,13 +8,15 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # degrades to skip without hypothesis
 
-from repro.configs import SHAPES, all_archs, get_config
+from pathlib import Path
+
+from repro.configs import SHAPES, get_config, traced_archs
 from repro.configs.base import ShapeConfig
 from repro.core.trace import model_flops, trace_lm
 from repro.workloads import lm_cell
 
 
-@pytest.mark.parametrize("arch", all_archs())
+@pytest.mark.parametrize("arch", traced_archs())
 def test_train_dfg_flops_vs_6nd(arch):
     """Traced DFG FLOPs should be ~6*N_active*D for train (plus attention,
     which 6ND ignores — so ratio in [0.95, 3.0])."""
@@ -27,7 +29,7 @@ def test_train_dfg_flops_vs_6nd(arch):
     assert 0.9 < ratio < 3.0, (arch, ratio)
 
 
-@pytest.mark.parametrize("arch", all_archs())
+@pytest.mark.parametrize("arch", traced_archs())
 def test_decode_dfg_much_smaller_than_prefill(arch):
     cfg = get_config(arch)
     if not cfg.subquadratic() and arch == "skip":
@@ -46,10 +48,30 @@ def test_moe_dfg_counts_active_experts_only():
 
 
 def test_vertex_stats_nonnegative():
-    for arch in all_archs():
+    for arch in traced_archs():
         g = lm_cell(arch, "train_4k")
         for f in (g.n_comp, g.n_read, g.n_write, g.n_alloc):
             assert float(jnp.min(f)) >= 0.0
+
+
+GRAPHS = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "graphs"
+
+
+@pytest.mark.parametrize("arch,shape,frozen", [
+    ("qwen2.5-32b", "prefill_32k", "qwen2.5-32b.prefill_32k.npz"),
+    ("kimi-k2-1t-a32b", "decode_32k", "kimi-k2-as-gqa.decode_32k.npz"),
+    ("kimi-k2-instruct", "prefill_32k", "kimi-k2-instruct.prefill_32k.npz"),
+    ("kimi-k2-instruct", "decode_32k", "kimi-k2-instruct.decode_32k.npz"),
+])
+def test_lm_cell_gives_the_benchmarks_frozen_graph(arch, shape, frozen):
+    """The chip benchmark prices these arrays (``freeze_graphs.py``): the
+    shared emitters may not drift from them by a bit."""
+    g = lm_cell(arch, shape)
+    with np.load(GRAPHS / frozen) as kept:
+        assert tuple(str(n) for n in kept["names"]) == g.names
+        for f in ("n_comp", "n_read", "n_write", "n_alloc", "dims", "op_kind", "edges"):
+            a = np.asarray(getattr(g, f))
+            assert kept[f].dtype == a.dtype and np.array_equal(kept[f], a), f
 
 
 class TestChunkedXentProperty:

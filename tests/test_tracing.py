@@ -5,7 +5,8 @@ nothing.  Under ``jax.profiler.trace`` a pooled service run records each
 chunk with its arguments and its stage / launch / fetch / report children
 on its own thread, carrying its chunk id; ``Session.optimize`` records one
 descent holding its fused chunks and their host syncs; a full collection
-records ``dragon.gc``.  Replies and optimize results are bit-identical with
+records ``dragon.gc``; tracing an LM records ``dragon.trace.lm`` with its
+vertices by role.  Replies and optimize results are bit-identical with
 and without a session.  This is the only test file that starts a profiler
 session.
 """
@@ -20,6 +21,7 @@ import pytest
 from repro.api import Session
 from repro.core import instrument
 from repro.serving import DesignQuery, FlushPolicy, PooledDesignService
+from repro.workloads import lm_cell
 
 
 def _events(trace_dir) -> list[dict]:
@@ -114,8 +116,11 @@ def recorded(tmp_path_factory):
                     pass
             with instrument.span("dragon.test.after"):
                 pass
+            lm = lm_cell("kimi-k2-instruct", "decode_32k")
+            sess.simulate_batch(["dlrm", "dlrm"])
             gc.collect()
-    return dict(events=_events(trace_dir), off=off, on=on, opt_off=opt_off, opt_on=opt_on)
+    return dict(events=_events(trace_dir), off=off, on=on, opt_off=opt_off, opt_on=opt_on,
+                lm_vertices=lm.n_vertices)
 
 
 class TestRecorded:
@@ -179,13 +184,30 @@ class TestRecorded:
         assert by_name["dragon.test.inner"]["args"] == {"lanes": 3, "chunk": 77}
         assert "chunk" not in by_name["dragon.test.after"]["args"]
 
+    def test_session_spans_carry_real_and_padded_vertices(self, recorded):
+        """``vertices`` (dlrm's 9) beside the padded ``bucket`` (32)."""
+        opt = [e for e in recorded["events"] if e["name"] == "dragon.session.optimize"]
+        assert opt[0]["args"]["vertices"] == 9 and opt[0]["args"]["bucket"] == "(1, 32)"
+        (batch,) = [e for e in recorded["events"] if e["name"] == "dragon.session.simulate_batch"]
+        assert (batch["args"]["n"], batch["args"]["vertices"]) == (2, 18)
+
+    def test_trace_lm_counts_vertices_by_role(self, recorded):
+        (lm,) = [e for e in recorded["events"] if e["name"] == "dragon.trace.lm"]
+        a = lm["args"]
+        assert (a["arch"], a["shape"], a["vertices"]) == ("kimi-k2-instruct", "decode_32k",
+                                                          recorded["lm_vertices"])
+        # 61 latent-attention blocks of 13, 60 routed blocks of 6 and shared
+        # experts of 3, one dense MLP of 4; embed, final norm and logits
+        assert (a["mla"], a["gqa"], a["routed"], a["shared"], a["dense"]) == (793, 0, 360, 180, 4)
+        assert a["vertices"] == 793 + 360 + 180 + 4 + 3
+
     def test_full_collection_recorded(self, recorded):
         gens = [e["args"]["generation"] for e in recorded["events"] if e["name"] == "dragon.gc"]
         assert 2 in gens
 
     def test_no_span_outside_the_dragon_names(self, recorded):
         layers = {e["name"].split(".")[1] for e in recorded["events"]}
-        assert layers <= {"service", "session", "dopt", "gc", "test"}
+        assert layers <= {"service", "session", "dopt", "gc", "trace", "test"}
 
 
 if __name__ == "__main__":
